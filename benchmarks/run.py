@@ -41,6 +41,9 @@ def main() -> None:
                          "CI tracks the serving perf trajectory per run)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_decode,
         bench_kernels,
@@ -111,7 +114,7 @@ def main() -> None:
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001 — a failing suite shouldn't kill the run
+        except Exception as e:  # noqa: BLE001 — later suites still run; exit 1 below
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}")
             rows = None
             results[name] = {"error": f"{type(e).__name__}:{e}"}
@@ -130,6 +133,10 @@ def main() -> None:
         }
         pathlib.Path(out).write_text(json.dumps(payload, indent=2) + "\n")
         print(f"# wrote {out}", file=sys.stderr)
+    failed = [n for n, r in results.items() if "error" in r]
+    if failed:
+        print(f"# suites failed: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

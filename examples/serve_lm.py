@@ -12,8 +12,11 @@ per-request budgets/seeds, paged KV pool — each request's stream matches
 the lockstep engine's for its seed); ``--packed`` serves the bit-packed
 integer export, so every decode linear runs the W1A8 GEMV kernel tier.
 
-Without --ckpt it serves a freshly initialised reduced model (tokens are
-synthetic ids); with a checkpoint from train_lm.py it decodes that model.
+Without --ckpt it serves a randomly initialised model from --seed at the
+arch's full width (tokens are synthetic ids; ``--reduced`` shrinks it);
+with a checkpoint from train_lm.py it decodes that model.  The compute
+dtype is the config's (bfloat16) unless ``--dtype`` overrides it.
+JAX's persistent compile cache is on (``repro.launch.compile_cache``).
 """
 
 import argparse
@@ -38,6 +41,10 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="random-init seed when no --ckpt is given")
+    ap.add_argument("--dtype", default=None, choices=["float32", "bfloat16"],
+                    help="compute dtype override (default: the config's)")
     ap.add_argument("--compare", action="store_true",
                     help="also time the legacy per-token Python loop")
     ap.add_argument("--stream", action="store_true",
@@ -69,7 +76,10 @@ def main():
                          "Defaults to REPRO_MESH when set")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh, mesh_from_env
+
+    enable_compile_cache()
 
     if args.mesh:
         data, model = (int(v) for v in args.mesh.lower().split("x"))
@@ -80,26 +90,36 @@ def main():
         print(f"serving on mesh {dict(mesh.shape)}")
 
     cfg = get_config(args.arch)
-    if args.reduced or not args.ckpt:
+    if args.reduced:
         cfg = reduced(cfg)
-    cfg = dataclasses.replace(cfg, dtype="float32")
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, dtype=args.dtype)
 
-    key = jax.random.PRNGKey(0)
+    from repro.train.quantized_serving import (
+        cast_serving_params,
+        init_serving_params,
+        quantize_params_for_serving,
+    )
+
+    key = jax.random.PRNGKey(args.seed)
     if args.ckpt:
         state, _ = init_train_state(key, cfg)
         restored = Checkpointer(args.ckpt).restore(state._asdict())
         params = restored["params"]
         print(f"restored checkpoint step {int(restored['opt'].step) if hasattr(restored['opt'], 'step') else '?'}")
+        if args.packed:
+            _, axes = api.params_shape_and_axes(cfg)
+            params, _ = quantize_params_for_serving(params, axes, cfg,
+                                                    packed=True)
+        params = cast_serving_params(params, cfg.dtype)
+    elif args.packed:
+        params, _ = init_serving_params(cfg, args.seed, packed=True)
     else:
-        params, _ = api.init_model(key, cfg)
-        print("serving a randomly initialised reduced model")
-
+        params = cast_serving_params(api.init_model(key, cfg)[0], cfg.dtype)
+    print(f"serving {cfg.name} ({cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, {cfg.dtype})"
+          + ("" if args.ckpt else f", random init from seed {args.seed}"))
     if args.packed:
-        from repro.train.quantized_serving import quantize_params_for_serving
-
-        _, axes = api.params_shape_and_axes(cfg)
-        params, _ = quantize_params_for_serving(params, axes, cfg,
-                                                packed=True)
         print("serving the packed integer export (W1A8 kernel tier)")
 
     scfg = SamplerConfig(temperature=0.8, top_k=40,

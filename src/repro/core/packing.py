@@ -6,8 +6,13 @@ W1A8 kernel streams packed tiles HBM->VMEM and unpacks in-register; this
 module provides the host-side pack/unpack and the pure-jnp oracle used by
 kernel tests.
 
-Bit convention: bit b of byte k along K encodes sign of weight k*8+b,
-bit=1 -> +1, bit=0 -> -1.  Little-endian within the byte.
+Bit convention (planar groups): K is cut into groups of 64 rows (8 packed
+rows); a last, shorter group takes the remaining ``K % 64`` rows.  In a
+group of ``g`` packed rows starting at K row ``base``, bit b of packed row
+i encodes the sign of weight ``base + b * g + i`` (bit=1 -> +1, bit=0 ->
+-1).  Unpacking a group is then 8 shifted planes stacked along K, with no
+interleave of the bit axis into the row axis: the form Mosaic lowers on
+TPU (``kernels.w1a8_matmul._unpack_tile``).
 """
 
 from __future__ import annotations
@@ -20,28 +25,62 @@ import numpy as np
 
 Array = jax.Array
 
+# K rows per planar bit group (8 packed uint8 rows).
+GROUP_K = 64
+# The packed kernels stream K in tiles that are multiples of this, or the
+# whole K when it is no larger; the serving export pads K up to it.
+KERNEL_K_ALIGN = 256
+
+
+def padded_k(k: int) -> int:
+    """K of the packed serving layout: a K that one kernel tile cannot hold
+    is padded to a multiple of ``KERNEL_K_ALIGN``.  Padded rows meet
+    zero-padded activations (``kernels.ops``), so they add nothing."""
+    if k <= KERNEL_K_ALIGN:
+        return k
+    return -(-k // KERNEL_K_ALIGN) * KERNEL_K_ALIGN
+
+
+def _groups(k: int) -> list[tuple[int, int]]:
+    """(first K row, packed rows) of each planar group of a K-row axis."""
+    out = [(s, GROUP_K // 8) for s in range(0, k - k % GROUP_K, GROUP_K)]
+    if k % GROUP_K:
+        out.append((k - k % GROUP_K, (k % GROUP_K) // 8))
+    return out
+
 
 def pack_signs(signs: Array) -> Array:
     """Pack +-1 (or bool) signs along the K (second-to-last) axis into uint8.
 
     signs: (..., K, N) with values in {-1, +1}; leading axes (layer stacks,
     expert stacks) pack per slice.  K must be a multiple of 8.
-    Returns (..., K//8, N) uint8.
+    Returns (..., K//8, N) uint8 in the planar group layout.
     """
     *lead, k, n = signs.shape
     assert k % 8 == 0, f"K={k} must be a multiple of 8"
-    bits = (signs > 0).astype(jnp.uint8).reshape(*lead, k // 8, 8, n)
-    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))[:, None]
-    return jnp.sum(bits * weights, axis=-2, dtype=jnp.uint8)
+    bits = (signs > 0).astype(jnp.uint8)
+    weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))[:, None, None]
+    parts = [
+        jnp.sum(
+            bits[..., s : s + 8 * g, :].reshape(*lead, 8, g, n) * weights,
+            axis=-3, dtype=jnp.uint8,
+        )
+        for s, g in _groups(k)
+    ]
+    return jnp.concatenate(parts, axis=-2)
 
 
 def unpack_signs(packed: Array, dtype=jnp.int8) -> Array:
     """Inverse of :func:`pack_signs`: (..., K//8, N) uint8 -> (..., K, N) +-1."""
     *lead, kb, n = packed.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None]
-    bits = (packed[..., :, None, :] >> shifts) & jnp.uint8(1)
-    signs = bits.astype(jnp.int8) * 2 - 1
-    return signs.reshape(*lead, kb * 8, n).astype(dtype)
+    shifts = jnp.arange(8, dtype=jnp.uint8)[:, None, None]
+    parts = []
+    for s, g in _groups(kb * 8):
+        rows = packed[..., None, s // 8 : s // 8 + g, :]
+        bits = (rows >> shifts) & jnp.uint8(1)  # (..., 8, g, N)
+        parts.append(bits.reshape(*lead, 8 * g, n))
+    signs = jnp.concatenate(parts, axis=-2).astype(jnp.int8) * 2 - 1
+    return signs.astype(dtype)
 
 
 @dataclasses.dataclass

@@ -300,7 +300,8 @@ def _dequant_stored(w: dict) -> Array:
     This float fallback is only for paths without a packed kernel (training
     utilities, routed 8-bit experts); the model forward dispatches packed
     layouts to ``repro.kernels.ops`` (``bit_linear_infer`` /
-    ``decoupled_first_gemm`` / ``int8_linear_infer``) instead."""
+    ``decoupled_first_gemm`` / ``int8_linear_infer``) instead.  A packed
+    K may carry the export's padding rows (``core.packing.padded_k``)."""
     if "packed" in w:
         from repro.core.packing import unpack_signs
 

@@ -20,11 +20,14 @@ paged_attention   | paged_attention.py  | paged-KV attention: decode    | models
                   |                     | GQA/MQA                       | (REPRO_PAGED_ATTN=1 forces on / =0 forces the
                   |                     |                               | gather+SDPA fallback / default: TPU only) AND
                   |                     |                               | ops.paged_attention_supported (GQA divides,
-                  |                     |                               | block_size & head_dim 8-aligned); MLA keeps
+                  |                     |                               | block_size fills the pool dtype's sublane
+                  |                     |                               | tile, head_dim 8-aligned); MLA keeps
                   |                     |                               | its dense latent cache (nothing paged to walk)
 
 Decode-tier tile sizes are answered per (M, K, N) signature by
-``ops.decode_tiles`` (divisor heuristic) and can be autotuned on the
+``ops.decode_tiles`` (Mosaic-legal heuristic: K tiles multiples of 256
+or the whole K, N tiles multiples of 128 with a partial last tile) and
+can be autotuned on the
 current backend with ``ops.sweep_decode_tiles`` — the swept winner is
 cached and picked up by later calls with the same signature.  The paged-
 attention pages-per-step knob is answered per (T, Hq, Hkv, head_dim,

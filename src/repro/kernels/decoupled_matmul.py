@@ -21,16 +21,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.w1a8_matmul import _unpack_tile
+from repro.core.packing import GROUP_K
+
+from repro.kernels.w1a8_matmul import (
+    SMEM_SPEC,
+    _unpack_tile,
+    int8_dot,
+    smem_scalars,
+)
 
 Array = jax.Array
 
-DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 256, 256
+DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 1024, 512
 
 
 def _decoupled_kernel(
-    x_ref, wp_ref, w8_ref, gamma_ref, lam_ref, w8s_ref, ab_ref,
-    o1_ref, o8_ref, acc1_ref, acc8_ref
+    x_ref, wp_ref, w8_ref, gamma_ref, s_ref, o1_ref, o8_ref, acc1_ref, acc8_ref
 ):
     j = pl.program_id(1)
 
@@ -41,30 +47,23 @@ def _decoupled_kernel(
 
     x = x_ref[...]
     w1 = _unpack_tile(wp_ref[...])
-    acc1_ref[...] += jax.lax.dot_general(
-        x, w1, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
+    acc1_ref[...] += int8_dot(x, w1)
     # 8-bit branch: only the j == 0 pass accumulates (r fits in one N tile;
     # other j tiles would redundantly recompute it)
     @pl.when(j == 0)
     def _acc8():
-        acc8_ref[...] += jax.lax.dot_general(
-            x, w8_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        acc8_ref[...] += int8_dot(x, w8_ref[...])
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _epilogue():
-        lam = lam_ref[0]
-        alpha, beta = ab_ref[0], ab_ref[1]
-        inv_gamma = 1.0 / gamma_ref[...]
-        y1 = acc1_ref[...].astype(jnp.float32) * (beta * lam * inv_gamma)[:, None]
+        lam, w8s, alpha, beta = (s_ref[0, i] for i in range(4))
+        gamma = gamma_ref[...]  # (bm, 1)
+        y1 = acc1_ref[...].astype(jnp.float32) * (beta * lam / gamma)
         o1_ref[...] = y1.astype(o1_ref.dtype)
 
         @pl.when(j == 0)
         def _write8():
-            inv8 = alpha / (gamma_ref[...] * w8s_ref[0])
-            y8 = acc8_ref[...].astype(jnp.float32) * inv8[:, None]
+            y8 = acc8_ref[...].astype(jnp.float32) * (alpha / (gamma * w8s))
             o8_ref[...] = y8.astype(o8_ref.dtype)
 
 
@@ -90,32 +89,32 @@ def decoupled_matmul(
     """Returns (y1 (M, N), y8 (M, R)): both branch outputs, scale-folded.
 
     R (the 8-bit width) must fit a single N tile (r <= bn) — true for the
-    paper's r in [128, 768] with bn = 256+ (pad in ops.py otherwise).
+    paper's r in [128, 768] with bn = 512+ (pad in ops.py otherwise).
+    M and K tile evenly; a ragged last N tile's extra columns are dropped.
     """
     m, k = x_i8.shape
     kb, n = w1_packed.shape
     _, r = w8_i8.shape
     assert kb * 8 == k
     bm_, bk_, bn_ = min(bm, m), min(bk, k), min(bn, n)
-    assert m % bm_ == 0 and k % bk_ == 0 and n % bn_ == 0
+    assert m % bm_ == 0 and k % bk_ == 0, (m, k, bm_, bk_)
+    assert bk_ == k or bk_ % GROUP_K == 0, (
+        f"bk={bk_} must hold whole {GROUP_K}-row bit groups")
     assert r <= bn_, f"8-bit width {r} must fit one tile (bn={bn_})"
 
-    ab = jnp.stack([alpha.astype(jnp.float32), beta.astype(jnp.float32)]).reshape(2)
     nk = k // bk_
     # w8 is only consumed on j == 0 passes; pin its block index at the last
     # K tile for j > 0 so the pipeline re-streams it per i, not per (i, j).
     w8_index = lambda i, j, kk: (jnp.where(j == 0, kk, nk - 1), 0)
     return pl.pallas_call(
         _decoupled_kernel,
-        grid=(m // bm_, n // bn_, nk),
+        grid=(m // bm_, pl.cdiv(n, bn_), nk),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk_ // 8, bn_), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk_, r), w8_index),
-            pl.BlockSpec((bm_,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
-            pl.BlockSpec((2,), lambda i, j, kk: (0,)),
+            pl.BlockSpec((bm_, 1), lambda i, j, kk: (i, 0)),
+            SMEM_SPEC,
         ],
         out_specs=[
             pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
@@ -134,8 +133,6 @@ def decoupled_matmul(
         x_i8,
         w1_packed,
         w8_i8,
-        gamma.astype(jnp.float32),
-        lam.reshape(1).astype(jnp.float32),
-        w8scale.reshape(1).astype(jnp.float32),
-        ab,
+        gamma.reshape(m, 1).astype(jnp.float32),
+        smem_scalars(lam, w8scale, alpha, beta),
     )

@@ -13,9 +13,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.w1a8_matmul import SMEM_SPEC, int8_dot, smem_scalars
+
 Array = jax.Array
 
-DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 256, 256
+DEFAULT_BM, DEFAULT_BK, DEFAULT_BN = 128, 512, 512
 
 
 def _int8_kernel(x_ref, w_ref, gamma_ref, wscale_ref, o_ref, acc_ref):
@@ -23,18 +25,12 @@ def _int8_kernel(x_ref, w_ref, gamma_ref, wscale_ref, o_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...],
-        w_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += int8_dot(x_ref[...], w_ref[...])
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _epilogue():
-        inv = 1.0 / (gamma_ref[...] * wscale_ref[0])  # (bm,)
-        y = acc_ref[...].astype(jnp.float32) * inv[:, None]
-        o_ref[...] = y.astype(o_ref.dtype)
+        inv = 1.0 / (gamma_ref[...] * wscale_ref[0, 0])  # (bm, 1)
+        o_ref[...] = (acc_ref[...].astype(jnp.float32) * inv).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -52,20 +48,21 @@ def int8_matmul(
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> Array:
+    """M and K tile evenly; a ragged last N tile's extra columns are dropped."""
     m, k = x_i8.shape
     k2, n = w_i8.shape
     assert k == k2
     bm_, bk_, bn_ = min(bm, m), min(bk, k), min(bn, n)
-    assert m % bm_ == 0 and k % bk_ == 0 and n % bn_ == 0
+    assert m % bm_ == 0 and k % bk_ == 0, (m, k, bm_, bk_)
 
     return pl.pallas_call(
         _int8_kernel,
-        grid=(m // bm_, n // bn_, k // bk_),
+        grid=(m // bm_, pl.cdiv(n, bn_), k // bk_),
         in_specs=[
             pl.BlockSpec((bm_, bk_), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk_, bn_), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm_,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
+            pl.BlockSpec((bm_, 1), lambda i, j, kk: (i, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -74,6 +71,6 @@ def int8_matmul(
     )(
         x_i8,
         w_i8,
-        gamma.astype(jnp.float32),
-        wscale.reshape(1).astype(jnp.float32),
+        gamma.reshape(m, 1).astype(jnp.float32),
+        smem_scalars(wscale),
     )

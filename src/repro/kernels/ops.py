@@ -13,6 +13,13 @@ the prefill-tiled kernels and the decode GEMV tier:
 * M > DECODE_M_MAX (prefill/train regime): the existing M-tiled kernels
   behind a separate ``quantize_act_int8`` pass.
 
+Mosaic tiling: K tiles are multiples of 256 (packed planar bit groups and
+uint8 sublane tiles) or the whole K; the serving export pads a longer
+packed K to a multiple of 256 (``core.packing.padded_k``) and the
+dispatchers zero-pad activations to match, so padded rows add nothing.  N
+tiles are multiples of 128 or the whole N; a ragged N runs a partial last
+tile whose extra columns the kernel never stores.
+
 Tile sizes for the decode tier come from a per-(M, K, N) dispatch table:
 ``decode_tiles`` answers from divisor heuristics, and ``sweep_decode_tiles``
 runs a timed sweep on the current backend and caches the winner under the
@@ -32,12 +39,13 @@ as fallback and parity oracle.
 from __future__ import annotations
 
 import functools
+import logging
 import os
+import re
 import time
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantization import quantize_act_int8  # noqa: F401  (re-export:
@@ -75,8 +83,12 @@ def _ensure_tile_cache_loaded() -> None:
     for key, tiles in tile_cache.load(jax.default_backend()).items():
         _DECODE_TILE_CACHE.setdefault(key, tiles)
 
-_BK_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
-_BN_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
+log = logging.getLogger(__name__)
+
+# K tiles of the packed kernels; int8 weights may also take 128.
+_BK_CANDIDATES = (1024, 512, 256)
+_BK_INT8_CANDIDATES = (1024, 512, 256, 128)
+_BN_CANDIDATES = (512, 256, 128)
 
 
 def _annotate(name: str):
@@ -92,6 +104,19 @@ def _annotate(name: str):
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+_KERNEL_RE = re.compile(
+    r"%([A-Za-z_][A-Za-z0-9_]*?)(?:\.\d+)? = [^\n]*"
+    r'custom_call_target="tpu_custom_call"')
+
+
+def pallas_kernels(compiled_text: str) -> set[str]:
+    """Names of the Pallas kernels in a compiled TPU program's text
+    (``jit(f).lower(...).compile().as_text()``): each kernel is a
+    ``tpu_custom_call`` named after its jitted wrapper (``w1a8_gemv``,
+    ``paged_attention``, ...).  Empty for an interpret-mode program."""
+    return set(_KERNEL_RE.findall(compiled_text))
 
 
 def _pad_rows(x: Array, mult: int):
@@ -117,11 +142,31 @@ def _pad_gamma(gamma: Array, mult: int) -> Array:
 # ---------------------------------------------------------------------------
 
 
-def _largest_divisor(total: int, candidates) -> int:
+def _k_tile(k: int, candidates=_BK_CANDIDATES) -> int:
+    """Widest candidate that divides K, else the whole K."""
     for c in candidates:
-        if c <= total and total % c == 0:
+        if c <= k and k % c == 0:
             return c
-    return total
+    return k
+
+
+def _n_tile(n: int, r: int | None = None, candidates=_BN_CANDIDATES) -> int:
+    """Widest candidate (>= r when given) that divides N; else the whole N
+    when it fits the widest candidate; else the widest candidate, run as a
+    partial last tile."""
+    lo = r or 1
+    for c in candidates:
+        if lo <= c <= n and n % c == 0:
+            return c
+    if n <= max(candidates[0], lo):
+        return n
+    return max(candidates[0], -(-lo // 128) * 128)
+
+
+def _pad_k(x: Array, k: int) -> Array:
+    """Zero-pad activations (M, K') to the weight's (padded) K."""
+    pad = k - x.shape[-1]
+    return jnp.pad(x, ((0, 0), (0, pad))) if pad else x
 
 
 def _tile_key(op: str, m: int, k: int, n: int, r: int | None):
@@ -133,21 +178,16 @@ def _tile_key(op: str, m: int, k: int, n: int, r: int | None):
 def decode_tiles(m: int, k: int, n: int, op: str = "w1a8_gemv",
                  r: int | None = None):
     """(bk, bn) for a decode-shaped call: autotuned entry if one was swept
-    (this process or a persisted earlier one), otherwise the widest
-    candidate tiles that divide (K, N).  For the decoupled op, bn always
-    fits the 8-bit branch (bn >= r)."""
+    (this process or a persisted earlier one), otherwise the Mosaic-legal
+    heuristic tiles (``_k_tile`` / ``_n_tile``).  For the decoupled op, bn
+    always fits the 8-bit branch (bn >= r)."""
     _ensure_tile_cache_loaded()
     cached = _DECODE_TILE_CACHE.get(_tile_key(op, m, k, n, r))
     if cached is not None:
         tile_cache.record_hit()
         return cached
     tile_cache.record_miss()
-    bk = _largest_divisor(k, _BK_CANDIDATES)
-    bn = _largest_divisor(n, _BN_CANDIDATES)
-    if r is not None and bn < r:
-        wide = [c for c in _BN_CANDIDATES if c >= r and n % c == 0]
-        bn = min(wide) if wide else n
-    return bk, bn
+    return _k_tile(k), _n_tile(n, r)
 
 
 def sweep_decode_tiles(
@@ -174,6 +214,8 @@ def sweep_decode_tiles(
     at server start-up; subsequent calls with that signature use the cache.
     Winners are mirrored to the per-backend on-disk cache
     (``repro.kernels.tile_cache``), so later processes skip the sweep.
+    On TPU a candidate the compiler refuses is counted and logged, and a
+    sweep in which no candidate compiles raises.
     """
     import numpy as np
 
@@ -199,32 +241,49 @@ def sweep_decode_tiles(
         def call(bk, bn):
             return w1a8_gemv(x, wp, lam, bk=bk, bn=bn, interpret=interp)
 
-    best, best_t = None, float("inf")
-    bks = [c for c in (bk_candidates or _BK_CANDIDATES)
-           if c % 8 == 0 and c <= k and k % c == 0]
-    bns = [c for c in (bn_candidates or _BN_CANDIDATES)
-           if c <= n and n % c == 0
-           and (op != "decoupled_gemv" or c >= r)]
-    for bk in bks:
-        for bn in bns:
-            try:
-                for _ in range(warmup):
-                    jax.block_until_ready(call(bk, bn))
-                ts = []
-                for _ in range(iters):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(call(bk, bn))
-                    ts.append(time.perf_counter() - t0)
-                t = min(ts)
-            except Exception:  # noqa: BLE001 — an invalid tile combo just loses
-                continue
-            if t < best_t:
-                best, best_t = (bk, bn), t
-    if best is None:
-        best = decode_tiles(m_p, k, n, op=op, r=r)
+    rr = r if op == "decoupled_gemv" else None
+    bks = sorted({c for c in (bk_candidates or _BK_CANDIDATES)
+                  if c % 8 == 0 and c <= k and k % c == 0} | {_k_tile(k)},
+                 reverse=True)
+    bns = sorted({c for c in (bn_candidates or _BN_CANDIDATES)
+                  if c <= n and n % c == 0 and c >= (rr or 1)}
+                 | {_n_tile(n, rr)}, reverse=True)
+    best = _timed_best(
+        [(bk, bn) for bk in bks for bn in bns],
+        lambda c: call(*c), warmup, iters, key,
+    )
     _DECODE_TILE_CACHE[key] = best
     tile_cache.store(jax.default_backend(), {key: best})
     tile_cache.record_sweep_ms((time.perf_counter() - sweep_t0) * 1e3)
+    return best
+
+
+def _timed_best(candidates, call, warmup: int, iters: int, key):
+    """The fastest candidate of a sweep.  A candidate that raises loses;
+    on TPU the refusals are counted and logged.  A sweep where none runs
+    raises (the heuristic tile is always a candidate)."""
+    best, best_t, refused = None, float("inf"), []
+    for c in candidates:
+        try:
+            for _ in range(warmup):
+                jax.block_until_ready(call(c))
+            ts = []
+            for _ in range(iters):
+                t0 = time.perf_counter()
+                jax.block_until_ready(call(c))
+                ts.append(time.perf_counter() - t0)
+        except Exception as e:  # noqa: BLE001 — refused candidates are counted
+            refused.append((c, e))
+            continue
+        if min(ts) < best_t:
+            best, best_t = c, min(ts)
+    if refused and on_tpu():
+        log.warning("tile sweep %s: %d of %d candidates refused: %s", key,
+                    len(refused), len(candidates),
+                    [c for c, _ in refused])
+    if best is None:
+        raise RuntimeError(f"tile sweep {key}: no candidate compiled") from (
+            refused[-1][1] if refused else None)
     return best
 
 
@@ -233,27 +292,22 @@ def sweep_decode_tiles(
 # ---------------------------------------------------------------------------
 
 
-def _prefill_tiles(k: int, n: int, r: int | None = None):
-    """(bk, bn): the widest candidate tiles that divide (K, N) — the
-    prefill-tier kernels assert even tiling, and model-stack shapes (e.g.
-    Mamba's d_inner = 384) aren't always multiples of the 256 defaults.
-    Ragged dims fall back to the whole dim (a single tile).  With ``r``
-    set, bn also fits the 8-bit branch (bn >= r)."""
-    bk = _largest_divisor(k, _BK_CANDIDATES)
-    bn = _largest_divisor(n, _BN_CANDIDATES)
-    if r is not None and bn < r:
-        wide = [c for c in _BN_CANDIDATES if c >= r and n % c == 0]
-        bn = min(wide) if wide else n
-    return bk, bn
+def _prefill_tiles(k: int, n: int, r: int | None = None,
+                   k_candidates=_BK_CANDIDATES):
+    """(bk, bn) for the M-tiled prefill kernels: Mosaic-legal tiles
+    (``_k_tile`` / ``_n_tile``); with ``r`` set, bn also fits the 8-bit
+    branch (bn >= r)."""
+    return _k_tile(k, k_candidates), _n_tile(n, r)
 
 
 def _bit_linear_prefill(xf: Array, w_packed: Array, lam: Array, out_dtype):
     """Prefill-tiled path: XLA act-quant pass + M-tiled w1a8_matmul."""
     xq, gamma = quantize_act_int8(xf)
+    xq = _pad_k(xq, w_packed.shape[0] * 8)
     bm = 8 if xq.shape[0] <= 128 else 128
     xq, m = _pad_rows(xq, bm)
     gamma_p = _pad_gamma(gamma, bm)
-    bk, bn = _prefill_tiles(xf.shape[1], w_packed.shape[1])
+    bk, bn = _prefill_tiles(xq.shape[1], w_packed.shape[1])
     with _annotate("kernels/w1a8_matmul"):
         y = w1a8_matmul(
             xq, w_packed, gamma_p, lam,
@@ -264,8 +318,8 @@ def _bit_linear_prefill(xf: Array, w_packed: Array, lam: Array, out_dtype):
 
 def _bit_linear_decode(xf: Array, w_packed: Array, lam: Array, out_dtype):
     """Decode GEMV path: act-quant fused into the kernel prologue."""
-    xp, m = _pad_rows(xf, 8)
-    bk, bn = decode_tiles(xp.shape[0], xf.shape[1], w_packed.shape[1])
+    xp, m = _pad_rows(_pad_k(xf, w_packed.shape[0] * 8), 8)
+    bk, bn = decode_tiles(xp.shape[0], xp.shape[1], w_packed.shape[1])
     with _annotate("kernels/w1a8_gemv"):
         y = w1a8_gemv(
             xp, w_packed, lam,
@@ -279,10 +333,16 @@ def bit_linear_infer(
 ) -> Array:
     """Full W1A8 inference linear: quantize acts -> packed 1-bit matmul.
 
-    x: (..., K) float; w_packed: (K//8, N) uint8; lam: scalar.
+    x: (..., K) float; w_packed: (Kp//8, N) uint8 with Kp >= K (the
+    export's padded K; activations are zero-padded to it); lam: scalar.
     Decode shapes (M <= DECODE_M_MAX flattened rows) take the fused GEMV
     tier; larger M takes the prefill-tiled kernel.
     """
+    fn = functools.partial(_bit_linear_local, out_dtype=out_dtype)
+    return _island(fn, (x, w_packed, lam))
+
+
+def _bit_linear_local(x: Array, w_packed: Array, lam: Array, out_dtype):
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     if xf.shape[0] <= DECODE_M_MAX:
@@ -296,10 +356,12 @@ def bit_linear_infer(
 # Tensor-parallel (N-major) kernel islands
 # ---------------------------------------------------------------------------
 #
-# GSPMD treats a pallas_call as opaque, so an N-sharded packed weight fed to
-# the plain dispatchers would be all-gathered around the kernel.  The
-# ``*_nshard`` wrappers instead open a ``shard_map`` island over the active
-# mesh: x / scales come in replicated, the weight comes in N-major-sharded,
+# GSPMD cannot partition a Mosaic kernel: on TPU a pallas_call in a program
+# over several devices must sit inside a ``shard_map``.  Every public
+# dispatcher therefore runs its kernel in an island over the active mesh
+# (``_island``; a no-op without a mesh or on one device).  The plain ones
+# take every operand replicated.  The ``*_nshard`` wrappers take x / scales
+# replicated and the weight N-major-sharded,
 # and each device runs the SAME kernel on its local (K, N/ws) shard — no
 # collective inside the island, the dot-product reduction is never split,
 # so per-shard outputs are bitwise slices of the unsharded result.  Because
@@ -310,6 +372,21 @@ def bit_linear_infer(
 
 def _rep(ndim: int) -> P:
     return P(*([None] * ndim))
+
+
+def _island(fn, args, in_specs=None, out_specs=None):
+    """``fn(*args)``, inside a ``shard_map`` over the active mesh when it
+    spans several devices.  Specs default to replicated."""
+    mesh = _sharding.active_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    if in_specs is None:
+        in_specs = tuple(_rep(jnp.ndim(a)) for a in args)
+    if out_specs is None:
+        out_specs = jax.tree.map(lambda o: _rep(o.ndim),
+                                 jax.eval_shape(fn, *args))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def _nshard(ndim: int, axis: str) -> P:
@@ -323,13 +400,12 @@ def bit_linear_infer_nshard(
     axis ``axis`` (callers decide via ``sharding.nmajor_axis``).  ``lam`` is
     the per-weight AbsMean scalar — replicated, so every shard dequantizes
     with the same scale (per-shard scales == the full scale)."""
-    mesh = _sharding.active_mesh()
-    fn = functools.partial(bit_linear_infer, out_dtype=out_dtype)
-    return shard_map(
-        fn, mesh=mesh,
-        in_specs=(_rep(x.ndim), _nshard(2, axis), _rep(lam.ndim)),
-        out_specs=_nshard(x.ndim, axis), check_rep=False,
-    )(x, w_packed, lam)
+    fn = functools.partial(_bit_linear_local, out_dtype=out_dtype)
+    return _island(
+        fn, (x, w_packed, lam),
+        (_rep(x.ndim), _nshard(2, axis), _rep(lam.ndim)),
+        _nshard(x.ndim, axis),
+    )
 
 
 def int8_linear_infer_nshard(
@@ -337,13 +413,12 @@ def int8_linear_infer_nshard(
 ) -> Array:
     """:func:`int8_linear_infer` with ``w_q`` sharded N-major; the AbsMax
     weight scale is a replicated scalar, shared by every shard."""
-    mesh = _sharding.active_mesh()
-    fn = functools.partial(int8_linear_infer, out_dtype=out_dtype)
-    return shard_map(
-        fn, mesh=mesh,
-        in_specs=(_rep(x.ndim), _nshard(2, axis), _rep(wscale.ndim)),
-        out_specs=_nshard(x.ndim, axis), check_rep=False,
-    )(x, w_q, wscale)
+    fn = functools.partial(_int8_linear_local, out_dtype=out_dtype)
+    return _island(
+        fn, (x, w_q, wscale),
+        (_rep(x.ndim), _nshard(2, axis), _rep(wscale.ndim)),
+        _nshard(x.ndim, axis),
+    )
 
 
 def decoupled_first_gemm_nshard(
@@ -361,30 +436,34 @@ def decoupled_first_gemm_nshard(
     The r-narrow 8-bit branch stays replicated (``ffn8`` maps to no mesh
     axis under the serving rules), so y1 comes out sharded and y8 comes out
     replicated."""
-    mesh = _sharding.active_mesh()
-    fn = functools.partial(decoupled_first_gemm, out_dtype=out_dtype)
-    return shard_map(
-        fn, mesh=mesh,
-        in_specs=(
+    fn = functools.partial(_decoupled_first_gemm_local, out_dtype=out_dtype)
+    return _island(
+        fn, (x, w1_packed, w8_q, lam, w8scale, alpha, beta),
+        (
             _rep(x.ndim), _nshard(2, axis), _rep(2), _rep(lam.ndim),
             _rep(w8scale.ndim), _rep(alpha.ndim), _rep(beta.ndim),
         ),
-        out_specs=(_nshard(x.ndim, axis), _rep(x.ndim)),
-        check_rep=False,
-    )(x, w1_packed, w8_q, lam, w8scale, alpha, beta)
+        (_nshard(x.ndim, axis), _rep(x.ndim)),
+    )
 
 
 def int8_linear_infer(
     x: Array, w_q: Array, wscale: Array, out_dtype=jnp.bfloat16
 ) -> Array:
     """Full W8A8 inference linear (8-bit branch)."""
+    fn = functools.partial(_int8_linear_local, out_dtype=out_dtype)
+    return _island(fn, (x, w_q, wscale))
+
+
+def _int8_linear_local(x: Array, w_q: Array, wscale: Array, out_dtype):
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     xq, gamma = quantize_act_int8(xf)
     bm = 8 if xq.shape[0] <= 128 else 128
     xq, m = _pad_rows(xq, bm)
     gamma_p = _pad_gamma(gamma, bm)
-    bk, bn = _prefill_tiles(xf.shape[1], w_q.shape[1])
+    bk, bn = _prefill_tiles(xf.shape[1], w_q.shape[1],
+                            k_candidates=_BK_INT8_CANDIDATES)
     with _annotate("kernels/int8_matmul"):
         y = int8_matmul(
             xq, w_q, gamma_p, wscale, bm=bm, bk=bk, bn=bn,
@@ -407,11 +486,13 @@ def _decoupled_prefill(
     xf, w1_packed, w8_q, lam, w8scale, alpha, beta, out_dtype
 ):
     xq, gamma = quantize_act_int8(xf)
+    xq = _pad_k(xq, w1_packed.shape[0] * 8)
     bm = 8 if xq.shape[0] <= 128 else 128
     xq, m = _pad_rows(xq, bm)
     gamma_p = _pad_gamma(gamma, bm)
+    w8_q, _ = _pad_rows(w8_q, xq.shape[1])  # zero rows meet zero acts
     r = w8_q.shape[1]
-    bk, bn = _prefill_tiles(xf.shape[1], w1_packed.shape[1], r=r)
+    bk, bn = _prefill_tiles(xq.shape[1], w1_packed.shape[1], r=r)
     with _annotate("kernels/decoupled_matmul"):
         y1, y8 = decoupled_matmul(
             xq, w1_packed, w8_q, gamma_p, lam, w8scale, alpha, beta,
@@ -423,8 +504,9 @@ def _decoupled_prefill(
 def _decoupled_decode(
     xf, w1_packed, w8_q, lam, w8scale, alpha, beta, out_dtype
 ):
-    xp, m = _pad_rows(xf, 8)
-    k, n, r = xf.shape[1], w1_packed.shape[1], w8_q.shape[1]
+    xp, m = _pad_rows(_pad_k(xf, w1_packed.shape[0] * 8), 8)
+    w8_q, _ = _pad_rows(w8_q, xp.shape[1])  # zero rows meet zero acts
+    k, n, r = xp.shape[1], w1_packed.shape[1], w8_q.shape[1]
     bk, bn = decode_tiles(xp.shape[0], k, n, op="decoupled_gemv", r=r)
     with _annotate("kernels/decoupled_gemv"):
         y1, y8 = decoupled_gemv(
@@ -449,6 +531,13 @@ def decoupled_first_gemm(
     Returns (y1 (..., N), y8 (..., R)), each pre-scaled by beta / alpha.
     Decode shapes route to the fused-act-quant ``decoupled_gemv``.
     """
+    fn = functools.partial(_decoupled_first_gemm_local, out_dtype=out_dtype)
+    return _island(fn, (x, w1_packed, w8_q, lam, w8scale, alpha, beta))
+
+
+def _decoupled_first_gemm_local(
+    x, w1_packed, w8_q, lam, w8scale, alpha, beta, out_dtype
+):
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     if xf.shape[0] <= DECODE_M_MAX:
@@ -483,23 +572,47 @@ def paged_attention_enabled() -> bool:
     """
     v = os.environ.get("REPRO_PAGED_ATTN", "auto")
     if v == "0":
+        _log_once(("paged_attn_off",),
+                  "REPRO_PAGED_ATTN=0: paged attention runs the gather path")
         return False
     if v == "1":
         return True
     return on_tpu()
 
 
+_LOGGED: set = set()
+
+
+def _log_once(key, msg: str, *args) -> None:
+    """Warn once per key on TPU, where a fallback costs real time."""
+    if on_tpu() and key not in _LOGGED:
+        _LOGGED.add(key)
+        log.warning(msg, *args)
+
+
 def paged_attention_supported(
-    block_size: int, head_dim: int, n_q_heads: int, n_kv_heads: int
+    block_size: int, head_dim: int, n_q_heads: int, n_kv_heads: int,
+    dtype=jnp.float32,
 ) -> bool:
     """Static shape gate for the kernel (callers fall back on False):
-    GQA grouping must divide evenly and page/head tiles must respect the
-    8-row packing/sublane alignment the kernel assumes."""
-    return (
+    GQA grouping must divide evenly, a page (block_size rows of one head)
+    must fill whole sublane tiles of the pool dtype (8 rows for f32, 16 for
+    bf16), and head_dim must be 8-aligned.  On TPU a refused shape is
+    logged once, so a gather fallback is visible."""
+    rows = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    ok = (
         n_q_heads % n_kv_heads == 0
-        and block_size % 8 == 0
+        and block_size % rows == 0
         and head_dim % 8 == 0
     )
+    if not ok:
+        shape = (block_size, head_dim, n_q_heads, n_kv_heads,
+                 jnp.dtype(dtype).name)
+        _log_once(("paged_attn", shape),
+                  "paged attention kernel refuses (block_size, head_dim, "
+                  "n_q_heads, n_kv_heads, dtype)=%s; using the gather path",
+                  shape)
+    return ok
 
 
 def paged_tiles(
@@ -545,10 +658,10 @@ def sweep_paged_tiles(
     nb = 2 * mb
     q = jnp.asarray(rng.standard_normal((2, t, hq, d)).astype(np.float32))
     kp = jnp.asarray(
-        rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+        rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
     )
     vp = jnp.asarray(
-        rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+        rng.standard_normal((nb, hkv, bs, d)).astype(np.float32)
     )
     table = jnp.asarray(
         rng.permutation(nb)[: 2 * mb].reshape(2, mb).astype(np.int32)
@@ -558,29 +671,12 @@ def sweep_paged_tiles(
     start = jnp.asarray([s0, min(bs, s0)], np.int32)
     lens = start + t
     interp = not on_tpu()
-    best, best_t = None, float("inf")
-    for pages in candidates or _PAGES_CANDIDATES:
-        if pages > mb:
-            continue
-        try:
-            call = functools.partial(
-                _paged_attention, q, kp, vp, table, start, lens,
-                pages=pages, interpret=interp,
-            )
-            for _ in range(warmup):
-                jax.block_until_ready(call())
-            ts = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                jax.block_until_ready(call())
-                ts.append(time.perf_counter() - t0)
-            dt = min(ts)
-        except Exception:  # noqa: BLE001 — an invalid candidate just loses
-            continue
-        if dt < best_t:
-            best, best_t = pages, dt
-    if best is None:
-        best = paged_tiles(t, hq, hkv, d, bs, mb)
+    best = _timed_best(
+        [p for p in (candidates or _PAGES_CANDIDATES) if p <= mb],
+        lambda p: _paged_attention(q, kp, vp, table, start, lens,
+                                   pages=p, interpret=interp),
+        warmup, iters, key,
+    )
     _DECODE_TILE_CACHE[key] = (best,)
     tile_cache.store(jax.default_backend(), {key: (best,)})
     tile_cache.record_sweep_ms((time.perf_counter() - sweep_t0) * 1e3)
@@ -589,8 +685,8 @@ def sweep_paged_tiles(
 
 def paged_attention(
     q: Array,  # (B, T, Hq, D)
-    kpool: Array,  # (NB, BS, Hkv, D)
-    vpool: Array,  # (NB, BS, Hkv, D)
+    kpool: Array,  # (NB, Hkv, BS, D)
+    vpool: Array,  # (NB, Hkv, BS, D)
     table: Array,  # (B, MB) int32
     start: Array,  # (B,) int32 — absolute position of q[:, 0]
     kv_lens: Array,  # (B,) int32 — resident tokens per slot
@@ -604,10 +700,24 @@ def paged_attention(
     off-TPU.  Callers gate on :func:`paged_attention_enabled` /
     :func:`paged_attention_supported` and keep the ``kv_pool.read``
     gather + SDPA path as fallback and parity oracle
-    (``ref.paged_attention_ref``).
+    (``ref.paged_attention_ref``).  Under a multi-device mesh it runs per
+    shard of KV heads, the pools' serving placement (``cache_heads``).
     """
+    fn = functools.partial(_paged_attention_local, scale=scale)
+    args = (q, kpool, vpool, table, start, kv_lens)
+    mesh = _sharding.active_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    pool = _sharding.relaxed_spec(
+        kpool.shape, (None, "cache_heads", None, None), mesh)
+    heads = P(None, None, pool[1], None)
+    return _island(fn, args, (heads, pool, pool, _rep(2), _rep(1), _rep(1)),
+                   heads)
+
+
+def _paged_attention_local(q, kpool, vpool, table, start, kv_lens, scale):
     t, hq, d = q.shape[1:]
-    bs, hkv = kpool.shape[1], kpool.shape[2]
+    hkv, bs = kpool.shape[1], kpool.shape[2]
     mb = table.shape[1]
     pages = paged_tiles(t, hq, hkv, d, bs, mb)
     with _annotate("kernels/paged_attention"):

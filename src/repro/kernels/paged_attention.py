@@ -9,7 +9,7 @@ a ``(B, max_blocks * block, H, D)`` copy of the pool *per layer per step*
 just so XLA's SDPA can read it.  This kernel consumes the paged layout
 directly:
 
-    kpool / vpool : (num_blocks, block, n_kv_heads, head_dim)
+    kpool / vpool : (num_blocks, n_kv_heads, block, head_dim)
     table         : (B, max_blocks) int32  — per-slot block ids
     start         : (B,) int32 — absolute position of the first query token
     kv_lens       : (B,) int32 — resident tokens per slot (after the write)
@@ -23,7 +23,9 @@ Design (one kernel serves all three engine tiers):
   start/length vectors as *scalar prefetch* operands: the K/V page for a
   grid step is selected by indexing the table inside the BlockSpec index
   map (``PrefetchScalarGridSpec``), so the pipeline DMAs pool pages
-  HBM->VMEM directly — the classic TPU paged-attention trick.
+  HBM->VMEM directly — the classic TPU paged-attention trick.  Heads sit
+  ahead of the block axis in the pool, so one page of one KV head is a
+  ``(block, head_dim)`` tile: the two minor dims Mosaic tiles.
 * **GQA/MQA head grouping.**  Queries are laid out (B, Hkv, T*G, D)
   (G = Hq // Hkv query heads per KV head), so one grid step scores every
   query row of one KV head against one K/V page tile: decode (T=1, rows =
@@ -65,8 +67,8 @@ Array = jax.Array
 
 NEG_INF = -1e30
 
-# Queries are padded to the f32 sublane minimum so (T*G, D) tiles are legal.
-_ROW_ALIGN = 8
+# Queries are padded to the bf16 sublane minimum so (T*G, D) tiles are legal.
+_ROW_ALIGN = 16
 
 
 def _paged_attention_kernel(
@@ -103,8 +105,8 @@ def _paged_attention_kernel(
     @pl.when(s * pages * bs < lens_ref[b])
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)  # (TGp, D)
-        k = jnp.concatenate([r[0, :, 0, :] for r in k_refs], axis=0)
-        v = jnp.concatenate([r[0, :, 0, :] for r in v_refs], axis=0)
+        k = jnp.concatenate([r[0, 0] for r in k_refs], axis=0)
+        v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
         tg, w = q.shape[0], pages * bs
         # causal prefix: query row r = t*g + gq sits at start[b] + t and
         # attends absolute columns j <= that position (== _span_mask)
@@ -122,13 +124,13 @@ def _paged_attention_kernel(
         )
         sc = jnp.where(mask, sc, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         # p is explicitly re-masked: when a whole tile is masked m_new can
         # stay at NEG_INF and exp(sc - m_new) would be 1, not 0
-        p = jnp.where(mask, jnp.exp(sc - m_new[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p,
             v.astype(jnp.float32),
             (((1,), (0,)), ((), ())),
@@ -140,7 +142,7 @@ def _paged_attention_kernel(
     def _epilogue():
         # l > 0 for every row: column 0 satisfies j <= start + t (start,
         # t >= 0) and page 0 is always processed, so no 0/0 lane exists
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...][:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -148,8 +150,8 @@ def _paged_attention_kernel(
 )
 def paged_attention(
     q: Array,  # (B, T, Hq, D)
-    kpool: Array,  # (NB, BS, Hkv, D)
-    vpool: Array,  # (NB, BS, Hkv, D)
+    kpool: Array,  # (NB, Hkv, BS, D)
+    vpool: Array,  # (NB, Hkv, BS, D)
     table: Array,  # (B, MB) int32
     start: Array,  # (B,) int32 — absolute position of q[:, 0]
     kv_lens: Array,  # (B,) int32 — resident tokens per slot (>= 1)
@@ -169,7 +171,7 @@ def paged_attention(
     via ``ops.paged_tiles``).
     """
     b, t, hq, d = q.shape
-    nb, bs, hkv, dk = kpool.shape
+    nb, hkv, bs, dk = kpool.shape
     mb = table.shape[1]
     assert d == dk and vpool.shape == kpool.shape, (q.shape, kpool.shape)
     assert hq % hkv == 0, f"GQA grouping needs Hq % Hkv == 0, got {hq}/{hkv}"
@@ -200,12 +202,12 @@ def paged_attention(
             # pipeline skips the DMA instead of streaming dead pages
             last = jnp.maximum((lens[bi] - 1) // bs, 0)
             i = jnp.minimum(jnp.minimum(i, last), mb - 1)
-            return (table[bi, i], 0, h, 0)
+            return (table[bi, i], h, 0, 0)
 
         return index
 
     page_spec = [
-        pl.BlockSpec((1, bs, 1, d), page_index_map(p)) for p in range(pages)
+        pl.BlockSpec((1, 1, bs, d), page_index_map(p)) for p in range(pages)
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -219,8 +221,8 @@ def paged_attention(
             (1, 1, tgp, d), lambda bi, h, s, *_: (bi, h, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((tgp,), jnp.float32),  # running max
-            pltpu.VMEM((tgp,), jnp.float32),  # running denominator
+            pltpu.VMEM((tgp, 1), jnp.float32),  # running max
+            pltpu.VMEM((tgp, 1), jnp.float32),  # running denominator
             pltpu.VMEM((tgp, d), jnp.float32),  # output accumulator
         ],
     )

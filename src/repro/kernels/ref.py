@@ -11,11 +11,18 @@ Array = jax.Array
 
 
 def unpack_ref(packed: Array) -> Array:
-    """(K//8, N) uint8 -> (K, N) int8 in {-1, +1} (little-endian bits)."""
+    """(K//8, N) uint8 -> (K, N) int8 in {-1, +1}, per weight row: row k
+    of a planar group of g packed rows starting at K row ``base`` is bit
+    ``(k - base) // g`` of packed row ``base // 8 + (k - base) % g``
+    (``core.packing``)."""
     kb, n = packed.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8)[None, :, None]
-    bits = (packed[:, None, :] >> shifts) & jnp.uint8(1)
-    return (bits.astype(jnp.int8) * 2 - 1).reshape(kb * 8, n)
+    k = jnp.arange(kb * 8)
+    base = k // 64 * 64
+    g = jnp.minimum(8, (kb * 8 - base) // 8)
+    row = base // 8 + (k - base) % g
+    bit = ((k - base) // g).astype(jnp.uint8)
+    bits = (packed[row] >> bit[:, None]) & jnp.uint8(1)
+    return bits.astype(jnp.int8) * 2 - 1
 
 
 def w1a8_matmul_ref(
@@ -107,8 +114,8 @@ def decoupled_gemv_ref(
 
 def paged_attention_ref(
     q: Array,  # (B, T, Hq, D)
-    kpool: Array,  # (NB, BS, Hkv, D)
-    vpool: Array,  # (NB, BS, Hkv, D)
+    kpool: Array,  # (NB, Hkv, BS, D)
+    vpool: Array,  # (NB, Hkv, BS, D)
     table: Array,  # (B, MB) int32
     start: Array,  # (B,) int32
     kv_lens: Array,  # (B,) int32 (unused: the causal mask already bounds
@@ -123,11 +130,11 @@ def paged_attention_ref(
     """
     del kv_lens
     b, t, hq, d = q.shape
-    bs, hkv = kpool.shape[1], kpool.shape[2]
+    hkv, bs = kpool.shape[1], kpool.shape[2]
     g = hq // hkv
     scale = d**-0.5 if scale is None else scale
-    keys = jnp.take(kpool, table, axis=0).reshape(b, -1, hkv, d)
-    vals = jnp.take(vpool, table, axis=0).reshape(b, -1, hkv, d)
+    keys = jnp.take(kpool, table, axis=0).swapaxes(2, 3).reshape(b, -1, hkv, d)
+    vals = jnp.take(vpool, table, axis=0).swapaxes(2, 3).reshape(b, -1, hkv, d)
     skv = keys.shape[1]
     qg = q.reshape(b, t, hkv, g, d).astype(jnp.float32)
     logits = (

@@ -26,10 +26,10 @@ DEFAULT_BM = 256
 def _rmsnorm_quant_kernel(x_ref, scale_ref, q_ref, gamma_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    normed = x * jax.lax.rsqrt(var + eps) * scale_ref[...].astype(jnp.float32)[None, :]
-    amax = jnp.max(jnp.abs(normed), axis=-1)
-    gamma = 127.0 / (amax + 1e-5)
-    q = jnp.clip(jnp.round(normed * gamma[:, None]), -127, 127)
+    normed = x * jax.lax.rsqrt(var + eps) * scale_ref[...].astype(jnp.float32)
+    amax = jnp.max(jnp.abs(normed), axis=-1, keepdims=True)
+    gamma = 127.0 / (amax + 1e-5)  # (bm, 1)
+    q = jnp.clip(jnp.round(normed * gamma), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     gamma_ref[...] = gamma
 
@@ -48,20 +48,21 @@ def rmsnorm_quant(
     bm_ = min(bm, m)
     assert m % bm_ == 0
 
-    return pl.pallas_call(
+    q, gamma = pl.pallas_call(
         functools.partial(_rmsnorm_quant_kernel, eps=eps),
         grid=(m // bm_,),
         in_specs=[
             pl.BlockSpec((bm_, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bm_, d), lambda i: (i, 0)),
-            pl.BlockSpec((bm_,), lambda i: (i,)),
+            pl.BlockSpec((bm_, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, d), jnp.int8),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, scale)
+    )(x, scale.reshape(1, d))
+    return q, gamma[:, 0]

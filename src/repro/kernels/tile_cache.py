@@ -7,7 +7,7 @@ max_blocks)`` paged-attention signature — but only in-process, so every
 server restart re-pays the sweep.  This module mirrors those tables to a
 per-backend JSON file:
 
-    $REPRO_TILE_CACHE_DIR/decode_tiles_{backend}.json
+    $REPRO_TILE_CACHE_DIR/decode_tiles_{backend}.v{LAYOUT_VERSION}.json
     (default: ~/.cache/repro/)
 
 ``ops`` loads the file lazily on the first tile lookup and appends every
@@ -21,7 +21,10 @@ and stable under concurrent writers (atomic replace; last writer wins on
 a per-key basis after merging with the on-disk content).  Values are
 kernel-family-shaped: ``[bk, bn]`` for the GEMV ops, ``[pages]`` for
 paged attention — both keys and values are variable-arity int tuples, so
-new kernel families extend the same file without a format bump.
+new kernel families extend the same file without a format bump.  A change
+to what a tile means (the packed weight layout, or which tiles the kernels
+accept) bumps ``LAYOUT_VERSION``, so winners swept under the old rules are
+never loaded.
 """
 
 from __future__ import annotations
@@ -33,6 +36,9 @@ import pathlib
 import tempfile
 
 _KEY_SEP = "|"
+# 2: planar 64-row bit groups; K tiles hold whole groups (multiples of 256
+# or the whole K), N tiles are multiples of 128 or the whole N
+LAYOUT_VERSION = 2
 
 _log = logging.getLogger(__name__)
 
@@ -96,7 +102,7 @@ def enabled() -> bool:
 def cache_path(backend: str) -> pathlib.Path:
     root = os.environ.get("REPRO_TILE_CACHE_DIR")
     base = pathlib.Path(root) if root else pathlib.Path.home() / ".cache" / "repro"
-    return base / f"decode_tiles_{backend}.json"
+    return base / f"decode_tiles_{backend}.v{LAYOUT_VERSION}.json"
 
 
 def _encode_key(key: tuple) -> str:

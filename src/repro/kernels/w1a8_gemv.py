@@ -33,13 +33,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.w1a8_matmul import _unpack_tile
+from repro.core.packing import GROUP_K
+
+from repro.kernels.w1a8_matmul import (
+    SMEM_SPEC,
+    _unpack_tile,
+    int8_dot,
+    smem_scalars,
+)
 
 Array = jax.Array
 
 # Wider-than-prefill defaults: weight streaming dominates, so bn leans wide;
-# bk stays a multiple of 8 (packing) and of 128 (MXU lane) where shapes allow.
-DEFAULT_BK, DEFAULT_BN = 512, 512
+# bk is a multiple of 256 (planar bit groups, uint8 sublane tiles).
+DEFAULT_BK, DEFAULT_BN = 1024, 512
 
 
 def _quant_prologue(x_ref, xq_ref, gamma_ref):
@@ -52,16 +59,18 @@ def _quant_prologue(x_ref, xq_ref, gamma_ref):
     with the fake-quant path; keep them in lockstep or packed-vs-fake-quant
     parity drifts."""
     xf = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1)
-    gamma = 127.0 / (amax + 1e-5)
-    xq_ref[...] = jnp.clip(jnp.round(xf * gamma[:, None]), -127, 127).astype(
-        jnp.int8
-    )
+    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+    gamma = 127.0 / (amax + 1e-5)  # (bm, 1)
+    xq_ref[...] = jnp.clip(jnp.round(xf * gamma), -127, 127).astype(jnp.int8)
     gamma_ref[...] = gamma
 
 
+def _x_tile(xq_ref, kk, bk: int):
+    return xq_ref[:, pl.ds(pl.multiple_of(kk * bk, bk), bk)]
+
+
 def _w1a8_gemv_kernel(
-    x_ref, wp_ref, lam_ref, o_ref, xq_ref, gamma_ref, acc_ref, *, bk: int
+    x_ref, wp_ref, s_ref, o_ref, xq_ref, gamma_ref, acc_ref, *, bk: int
 ):
     """One (j, kk) grid step: j walks N tiles, kk walks K tiles (innermost)."""
     j, kk = pl.program_id(0), pl.program_id(1)
@@ -74,20 +83,13 @@ def _w1a8_gemv_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x_tile = xq_ref[:, pl.dslice(kk * bk, bk)]
     w_tile = _unpack_tile(wp_ref[...])
-    acc_ref[...] += jax.lax.dot_general(
-        x_tile,
-        w_tile,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += int8_dot(_x_tile(xq_ref, kk, bk), w_tile)
 
     @pl.when(kk == pl.num_programs(1) - 1)
     def _epilogue():
-        lam = lam_ref[0]
-        y = acc_ref[...].astype(jnp.float32) * (lam / gamma_ref[...])[:, None]
-        o_ref[...] = y.astype(o_ref.dtype)
+        scale = s_ref[0, 0] / gamma_ref[...]  # (bm, 1)
+        o_ref[...] = (acc_ref[...].astype(jnp.float32) * scale).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -107,36 +109,39 @@ def w1a8_gemv(
 
     x: (M, K) float activations, M small (decode rows; pad to 8 in ops.py);
     w_packed: (K//8, N) uint8 sign bits; lam: scalar AbsMean weight scale.
-    K % bk == 0 and N % bn == 0 (pick tiles via ops.decode_tiles).
+    K % bk == 0 (pick tiles via ops.decode_tiles); a ragged last N tile's
+    extra columns are dropped.
     """
     m, k = x.shape
     kb, n = w_packed.shape
     assert kb * 8 == k, f"packed K mismatch: {kb}*8 != {k}"
     bk_, bn_ = min(bk, k), min(bn, n)
     assert bk_ % 8 == 0, f"bk={bk_} must be a multiple of 8 (packing)"
-    assert k % bk_ == 0 and n % bn_ == 0, (k, n, bk_, bn_)
+    assert k % bk_ == 0, (k, bk_)
+    assert bk_ == k or bk_ % GROUP_K == 0, (
+        f"bk={bk_} must hold whole {GROUP_K}-row bit groups")
 
     return pl.pallas_call(
         functools.partial(_w1a8_gemv_kernel, bk=bk_),
-        grid=(n // bn_, k // bk_),
+        grid=(pl.cdiv(n, bn_), k // bk_),
         in_specs=[
             pl.BlockSpec((m, k), lambda j, kk: (0, 0)),  # resident in VMEM
             pl.BlockSpec((bk_ // 8, bn_), lambda j, kk: (kk, j)),
-            pl.BlockSpec((1,), lambda j, kk: (0,)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((m, bn_), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[
             pltpu.VMEM((m, k), jnp.int8),  # quantized rows
-            pltpu.VMEM((m,), jnp.float32),  # gamma
+            pltpu.VMEM((m, 1), jnp.float32),  # gamma
             pltpu.VMEM((m, bn_), jnp.int32),  # accumulator
         ],
         interpret=interpret,
-    )(x, w_packed, lam.reshape(1).astype(jnp.float32))
+    )(x, w_packed, smem_scalars(lam))
 
 
 def _decoupled_gemv_kernel(
-    x_ref, wp_ref, w8_ref, lam_ref, w8s_ref, ab_ref,
+    x_ref, wp_ref, w8_ref, s_ref,
     o1_ref, o8_ref, xq_ref, gamma_ref, acc1_ref, acc8_ref, *, bk: int
 ):
     j, kk = pl.program_id(0), pl.program_id(1)
@@ -150,33 +155,25 @@ def _decoupled_gemv_kernel(
     def _init():
         acc1_ref[...] = jnp.zeros_like(acc1_ref)
 
-    x_tile = xq_ref[:, pl.dslice(kk * bk, bk)]
+    x_tile = _x_tile(xq_ref, kk, bk)
     w1 = _unpack_tile(wp_ref[...])
-    acc1_ref[...] += jax.lax.dot_general(
-        x_tile, w1, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-    )
+    acc1_ref[...] += int8_dot(x_tile, w1)
 
     # 8-bit branch: only the j == 0 pass accumulates (r fits one N tile)
     @pl.when(j == 0)
     def _acc8():
-        acc8_ref[...] += jax.lax.dot_general(
-            x_tile, w8_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        acc8_ref[...] += int8_dot(x_tile, w8_ref[...])
 
     @pl.when(kk == pl.num_programs(1) - 1)
     def _epilogue():
-        lam = lam_ref[0]
-        alpha, beta = ab_ref[0], ab_ref[1]
-        y1 = acc1_ref[...].astype(jnp.float32) * (
-            beta * lam / gamma_ref[...]
-        )[:, None]
+        lam, w8s, alpha, beta = (s_ref[0, i] for i in range(4))
+        gamma = gamma_ref[...]  # (bm, 1)
+        y1 = acc1_ref[...].astype(jnp.float32) * (beta * lam / gamma)
         o1_ref[...] = y1.astype(o1_ref.dtype)
 
         @pl.when(j == 0)
         def _write8():
-            inv8 = alpha / (gamma_ref[...] * w8s_ref[0])
-            y8 = acc8_ref[...].astype(jnp.float32) * inv8[:, None]
+            y8 = acc8_ref[...].astype(jnp.float32) * (alpha / (gamma * w8s))
             o8_ref[...] = y8.astype(o8_ref.dtype)
 
 
@@ -208,12 +205,11 @@ def decoupled_gemv(
     _, r = w8_i8.shape
     assert kb * 8 == k, f"packed K mismatch: {kb}*8 != {k}"
     bk_, bn_ = min(bk, k), min(bn, n)
-    assert bk_ % 8 == 0 and k % bk_ == 0 and n % bn_ == 0, (k, n, bk_, bn_)
+    assert bk_ % 8 == 0 and k % bk_ == 0, (k, bk_)
+    assert bk_ == k or bk_ % GROUP_K == 0, (
+        f"bk={bk_} must hold whole {GROUP_K}-row bit groups")
     assert r <= bn_, f"8-bit width {r} must fit one tile (bn={bn_})"
 
-    ab = jnp.stack(
-        [alpha.astype(jnp.float32), beta.astype(jnp.float32)]
-    ).reshape(2)
     nk = k // bk_
     # w8 is only consumed on the j == 0 pass; pinning its block index at the
     # last K tile for j > 0 means the mapped block never changes after that
@@ -222,14 +218,12 @@ def decoupled_gemv(
     w8_index = lambda j, kk: (jnp.where(j == 0, kk, nk - 1), 0)
     return pl.pallas_call(
         functools.partial(_decoupled_gemv_kernel, bk=bk_),
-        grid=(n // bn_, nk),
+        grid=(pl.cdiv(n, bn_), nk),
         in_specs=[
             pl.BlockSpec((m, k), lambda j, kk: (0, 0)),
             pl.BlockSpec((bk_ // 8, bn_), lambda j, kk: (kk, j)),
             pl.BlockSpec((bk_, r), w8_index),
-            pl.BlockSpec((1,), lambda j, kk: (0,)),
-            pl.BlockSpec((1,), lambda j, kk: (0,)),
-            pl.BlockSpec((2,), lambda j, kk: (0,)),
+            SMEM_SPEC,
         ],
         out_specs=[
             pl.BlockSpec((m, bn_), lambda j, kk: (0, j)),
@@ -241,16 +235,9 @@ def decoupled_gemv(
         ],
         scratch_shapes=[
             pltpu.VMEM((m, k), jnp.int8),
-            pltpu.VMEM((m,), jnp.float32),
+            pltpu.VMEM((m, 1), jnp.float32),
             pltpu.VMEM((m, bn_), jnp.int32),
             pltpu.VMEM((m, r), jnp.int32),
         ],
         interpret=interpret,
-    )(
-        x,
-        w1_packed,
-        w8_i8,
-        lam.reshape(1).astype(jnp.float32),
-        w8scale.reshape(1).astype(jnp.float32),
-        ab,
-    )
+    )(x, w1_packed, w8_i8, smem_scalars(lam, w8scale, alpha, beta))

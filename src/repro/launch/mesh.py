@@ -12,12 +12,25 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """The one place that builds a mesh: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which a
+    ``pallas_call`` outside a ``shard_map`` is refused and
+    ``with_sharding_constraint`` rejects logical specs; the serving and
+    training paths rely on GSPMD propagation, i.e. ``Auto`` axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -37,7 +50,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"requested a {data}x{model} (data, model) mesh = {need} devices "
             f"but only {have} are visible; on CPU, force extra devices with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def mesh_from_env(var: str = "REPRO_MESH"):

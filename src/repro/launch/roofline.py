@@ -1,5 +1,7 @@
 import os
 
+# host-device dry run: the 512 virtual devices are CPU devices, never a TPU
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS_EXTRA", "")

@@ -44,7 +44,8 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--data", default=None, help="text file path (default: synthetic)")
     ap.add_argument("--dtype", default=None, choices=["float32", "bfloat16"],
-                    help="model compute dtype override (fp32 is faster on CPU)")
+                    help="model compute dtype override (default: the "
+                         "config's bfloat16; fp32 is faster on CPU)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
@@ -72,6 +73,10 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[list[str]] = None):
     args = build_argparser().parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.coordinator:
         jax.distributed.initialize(

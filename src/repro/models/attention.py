@@ -354,9 +354,9 @@ def _paged_scores(
     from repro.kernels import ops  # deferred: kernels tier is optional here
 
     b, t = q.shape[:2]
-    bs = kpool.shape[1]
+    bs = kpool.shape[2]
     if ops.paged_attention_enabled() and ops.paged_attention_supported(
-        bs, q.shape[-1], q.shape[2], kpool.shape[2]
+        bs, q.shape[-1], q.shape[2], kpool.shape[1], kpool.dtype
     ):
         kv_lens = jnp.clip(posv + n_valid, 1, table.shape[1] * bs)
         return ops.paged_attention(

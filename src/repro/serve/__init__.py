@@ -33,7 +33,7 @@ work builds on.
 Cache-adapter protocol: decode caches are per-layer dicts in one of two
 interchangeable layouts — dense ``{"k", "v"}`` ring buffers, or paged
 ``{"kpool", "vpool", "table"}`` backed by the shared block pool in
-:mod:`repro.serve.kv_pool` (a ``(num_blocks, block, n_kv_heads, head_dim)``
+:mod:`repro.serve.kv_pool` (a ``(num_blocks, n_kv_heads, block, head_dim)``
 pool per global-attention layer plus per-slot block tables; sliding-window
 layers keep their dense ring caches, whose length *is* the window).  The
 model stack dispatches on the ``"table"`` key, so every engine tier runs

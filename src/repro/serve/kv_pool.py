@@ -5,10 +5,12 @@ every slot owns ``max_len`` positions whether it uses them or not, so a
 short request admitted next to a long one pays the long one's memory.  The
 paged layout replaces the per-slot buffer with a shared pool
 
-    kpool / vpool : (num_blocks, block_size, n_kv_heads, head_dim)
+    kpool / vpool : (num_blocks, n_kv_heads, block_size, head_dim)
     table         : (B, max_blocks) int32  — per-slot block ids
 
-where position ``p`` of slot ``b`` lives at ``(table[b, p // bs], p % bs)``.
+where position ``p`` of slot ``b`` lives at ``(table[b, p // bs], :,
+p % bs)``.  Heads sit ahead of the block axis so one page of one head is a
+``(block_size, head_dim)`` tile for ``kernels.paged_attention``.
 Blocks are handed out by the host-side :class:`BlockAllocator` at admission
 and chunk boundaries and reclaimed on eviction, so KV memory scales with
 the *live* token count, not ``B * max_len``.
@@ -62,7 +64,7 @@ def init_paged_attention_cache(
             f"({block_size}) so prefill pages tile exactly"
         )
     max_blocks = blocks_for(max_len, block_size)
-    pool_shape = (num_blocks, block_size, n_kv_heads, head_dim)
+    pool_shape = (num_blocks, n_kv_heads, block_size, head_dim)
     cache = {
         "kpool": jnp.zeros(pool_shape, dtype),
         "vpool": jnp.zeros(pool_shape, dtype),
@@ -70,8 +72,8 @@ def init_paged_attention_cache(
     }
     axes = {
         # pools carry no batch axis — they are the shared resource
-        "kpool": (None, None, "cache_heads", None),
-        "vpool": (None, None, "cache_heads", None),
+        "kpool": (None, "cache_heads", None, None),
+        "vpool": (None, "cache_heads", None, None),
         "table": ("batch", None),
     }
     return cache, axes
@@ -83,8 +85,8 @@ def init_paged_attention_cache(
 # tables and dense ring caches follow the `batch` rule, which the serving
 # overrides map to None (replicated with the rest of the slot state).
 CACHE_KEY_AXES: dict[str, tuple] = {
-    "kpool": (None, None, "cache_heads", None),
-    "vpool": (None, None, "cache_heads", None),
+    "kpool": (None, "cache_heads", None, None),
+    "vpool": (None, "cache_heads", None, None),
     "table": ("batch", None),
     "k": ("batch", "cache_seq", "cache_heads", None),
     "v": ("batch", "cache_seq", "cache_heads", None),
@@ -123,7 +125,7 @@ def cache_sharding(cache_tree, mesh):
 
 
 def write(
-    pool: Array,  # (NB, BS, H, D)
+    pool: Array,  # (NB, H, BS, D)
     table: Array,  # (B, MB) int32
     pos: Array,  # (B,) int32 — write position per slot
     val: Array,  # (B, H, D) — one token's K or V per slot
@@ -132,16 +134,16 @@ def write(
     """Scatter one token per slot into its block.  Inactive slots are
     routed out of bounds and dropped, so a finished request can never
     scribble into a block that has been reclaimed and reassigned."""
-    bs = pool.shape[1]
+    bs = pool.shape[2]
     blk = jnp.take_along_axis(table, (pos // bs)[:, None], axis=1)[:, 0]
     off = pos % bs
     if active is not None:
         blk = jnp.where(active, blk, pool.shape[0])  # OOB -> mode="drop"
-    return pool.at[blk, off].set(val.astype(pool.dtype), mode="drop")
+    return pool.at[blk, :, off].set(val.astype(pool.dtype), mode="drop")
 
 
 def write_span(
-    pool: Array,  # (NB, BS, H, D)
+    pool: Array,  # (NB, H, BS, D)
     table: Array,  # (B, MB) int32
     pos: Array,  # (B,) int32 — first write position per slot
     val: Array,  # (B, T, H, D) — T consecutive tokens per slot
@@ -149,7 +151,7 @@ def write_span(
     lengths: Array | None = None,  # (B,) int32; tokens t >= lengths[b] dropped
 ) -> Array:
     """Scatter a span of T tokens per slot into its pages: position
-    ``pos[b] + t`` lands at ``(table[b, (pos[b]+t) // BS], (pos[b]+t) % BS)``.
+    ``pos[b] + t`` lands at ``(table[b, (pos[b]+t) // BS], :, (pos[b]+t) % BS)``.
 
     This is the multi-token generalisation of :func:`write` that both
     chunked prefill (prompt slices land directly in pool pages) and
@@ -159,7 +161,7 @@ def write_span(
     final slice) are routed out of bounds and dropped, exactly like
     :func:`write`'s inactive slots.
     """
-    bs = pool.shape[1]
+    bs = pool.shape[2]
     t = val.shape[1]
     p = pos[:, None] + jnp.arange(t, dtype=pos.dtype)[None, :]  # (B, T)
     mb = table.shape[1]
@@ -170,7 +172,7 @@ def write_span(
     if active is not None:
         ok = ok & active[:, None]
     blk = jnp.where(ok, blk, pool.shape[0])  # OOB -> mode="drop"
-    return pool.at[blk, p % bs].set(val.astype(pool.dtype), mode="drop")
+    return pool.at[blk, :, p % bs].set(val.astype(pool.dtype), mode="drop")
 
 
 def read(pool: Array, table: Array, blocks: int | None = None) -> Array:
@@ -189,7 +191,7 @@ def read(pool: Array, table: Array, blocks: int | None = None) -> Array:
     """
     if blocks is not None:
         table = table[:, : max(1, min(int(blocks), table.shape[1]))]
-    g = jnp.take(pool, table, axis=0)  # (B, nb, BS, H, D)
+    g = jnp.take(pool, table, axis=0).swapaxes(2, 3)  # (B, nb, BS, H, D)
     b, nb, bs = g.shape[:3]
     return g.reshape(b, nb * bs, *g.shape[3:])
 

@@ -300,7 +300,7 @@ def _make_install_fn(cfg: ModelConfig, nb: int):
                     # span-write exactly its nb prompt-covering pages (the
                     # static slice keeps the scatter O(nb * bs), not
                     # O(max_len))
-                    bs = pool.shape[1]
+                    bs = pool.shape[2]
                     return kv_pool.write_span(
                         pool, table_row[None], start, dense[:, : nb * bs]
                     )

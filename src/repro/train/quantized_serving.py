@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.packing import pack_signs
+from repro.core.packing import pack_signs, padded_k
 
 Array = jax.Array
 
@@ -64,13 +64,18 @@ def _binarize_export(w: Array, packed: bool, name: str = ""):
     bit-pack exactly like plain 2-D linears.  A K that isn't byte-aligned
     cannot pack (the kernels stream whole uint8 K-bytes); that case falls
     back to unpacked INT8 signs with an explicit warning instead of
-    silently losing the 16x weight-traffic story."""
+    silently losing the 16x weight-traffic story.  A packed K longer than
+    one kernel tile is padded to ``padded_k`` (extra rows hold -1 signs
+    and meet zero-padded activations, so they add nothing)."""
     red = tuple(range(max(0, w.ndim - 2), w.ndim))
     mu = jnp.mean(w, axis=red, keepdims=True)
     lam = (jnp.mean(jnp.abs(w), axis=red, keepdims=True) + 1e-5).astype(jnp.float32)
     signs = jnp.where(w - mu >= 0, jnp.int8(1), jnp.int8(-1))
     if packed:
-        if w.shape[-2] % 8 == 0:
+        k = w.shape[-2]
+        if k % 8 == 0:
+            pad = [(0, 0)] * (w.ndim - 2) + [(0, padded_k(k) - k), (0, 0)]
+            signs = jnp.pad(signs, pad, constant_values=-1)
             return {"packed": pack_signs(signs), "scale": lam}
         warnings.warn(
             f"packed export of {name or 'a 1-bit weight'} "
@@ -137,6 +142,41 @@ def quantize_params_for_serving(
             flat_axes.append(tuple(leaf_axes))
     qparams = jtu.tree_unflatten(treedef, new_leaves)
     qaxes = jtu.tree_unflatten(treedef, flat_axes)
+    return qparams, qaxes
+
+
+def cast_serving_params(qparams, dtype):
+    """Float leaves of a serving tree in the model's compute dtype, so
+    activations and KV caches follow ``cfg.dtype``; ``scale`` leaves (export
+    dequant scales, norm weights) stay float32."""
+    import jax.tree_util as jtu
+
+    dtype = jnp.dtype(dtype)
+
+    def cast(path, leaf):
+        if leaf.dtype != jnp.float32 or _path_keys(path)[-1] == "scale":
+            return leaf
+        return leaf.astype(dtype)
+
+    return jtu.tree_map_with_path(cast, qparams)
+
+
+def init_serving_params(cfg: ModelConfig, seed: int = 0, packed: bool = True):
+    """Randomly initialised serving weights in ``cfg.dtype``, as (qparams,
+    qaxes): the model init and the export run as one compiled program, so
+    the latent float tree exists only inside it and never sits in device
+    memory beside the exported integers."""
+    from repro.models import api
+
+    _, axes = api.params_shape_and_axes(cfg)
+
+    def build(key):
+        params, _ = api.init_model(key, cfg)
+        qparams = quantize_params_for_serving(params, axes, cfg, packed)[0]
+        return cast_serving_params(qparams, cfg.dtype)
+
+    qparams = jax.jit(build)(jax.random.PRNGKey(seed))
+    _, qaxes = serving_params_shape_and_axes(cfg, packed)
     return qparams, qaxes
 
 

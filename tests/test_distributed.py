@@ -15,6 +15,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_mesh
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -23,7 +24,7 @@ def run_subprocess(code: str) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = REPO_SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"  # the forced host devices; never a TPU
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, env=env, timeout=600,
@@ -34,19 +35,19 @@ def run_subprocess(code: str) -> dict:
 
 class TestRules:
     def test_logical_to_spec(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with sh.sharding_rules(mesh, None):
             spec = sh.logical_to_spec(("batch", None, "ffn"))
             assert spec == P("data", None, "model")
 
     def test_pod_axis_dropped_on_single_pod(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with sh.sharding_rules(mesh, None):
             # batch -> (pod, data); pod missing on this mesh
             assert sh.logical_to_spec(("batch",)) == P("data")
 
     def test_overrides(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with sh.sharding_rules(mesh, {"cache_seq": "data"}):
             assert sh.logical_to_spec(("cache_seq",)) == P("data")
 
@@ -59,7 +60,7 @@ class TestRules:
     def test_param_sharding_relaxes_indivisible(self):
         import jax.numpy as jnp
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with sh.sharding_rules(mesh, None):
             tree = {"w": jax.ShapeDtypeStruct((3, 4), jnp.float32)}
             axes = {"w": ("embed", "ffn")}
@@ -74,8 +75,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.sharding import sharding_rules
 from repro.distributed.qgather import binarize_gather
 from repro.core.quantization import binarize_weights
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 w = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
 ws = jax.device_put(w, NamedSharding(mesh, P("data", "model")))
 
@@ -98,15 +100,15 @@ print(json.dumps({"ok_val": ok_val, "ok_grad": ok_grad, "ok_int8": ok_int8}))
 MULTIDEV_COMPRESSION = """
 import jax, jax.numpy as jnp, numpy as np, json, functools
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.distributed.compression import compress_psum
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 0.1
 res = jnp.zeros((8, 64))
 
-@functools.partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=(P(), P("data")), check_rep=False)
+@functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+                   out_specs=(P(), P("data")), check_vma=False)
 def reduce_fn(g, r):
     mean, new_r = compress_psum(g[0], r[0], "data", chunk=16)
     return mean, new_r[None]

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.packing import export_bit_weight, pack_signs
+from repro.core.packing import export_bit_weight, pack_signs, padded_k
 from repro.kernels import ops, ref
 from repro.kernels.w1a8_gemv import decoupled_gemv, w1a8_gemv
 
@@ -24,7 +24,7 @@ def _inputs(m, k, n, seed=0):
 
 GEMV_CASES = [
     # (m, k, n, bk, bn)
-    (8, 64, 64, 32, 32),
+    (8, 64, 64, 64, 32),
     (8, 256, 512, 128, 256),
     (16, 512, 256, 512, 128),
     (32, 128, 384, 64, 128),
@@ -96,6 +96,31 @@ def test_decoupled_dispatch_ragged_m(m):
     )
     r1, r8 = ref.decoupled_gemv_ref(x, wp, w8, lam, w8s, alpha, beta)
     assert y1.shape == (m, n) and y8.shape == (m, r)
+    assert np.abs(np.asarray(y1) - np.asarray(r1)).max() <= TOL
+    assert np.abs(np.asarray(y8) - np.asarray(r8)).max() <= TOL
+
+
+@pytest.mark.parametrize("m", [4, 40])
+def test_decoupled_dispatch_padded_k(m):
+    """A K the serving export pads (320 -> 512), decode and prefill tier:
+    the padded sign rows and the dispatcher's zero 8-bit rows meet zero
+    activation columns, so both branches equal the unpadded computation."""
+    from repro.train.quantized_serving import _binarize_export
+
+    k, n, r = 320, 128, 16
+    rng = np.random.default_rng(m)
+    x = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    e = _binarize_export(jnp.asarray(rng.standard_normal((k, n)), jnp.float32),
+                         packed=True)
+    assert e["packed"].shape[0] * 8 == padded_k(k) > k
+    lam = e["scale"].reshape(())
+    w8 = jnp.asarray(rng.integers(-127, 128, (k, r)).astype(np.int8))
+    w8s, alpha, beta = (jnp.asarray(np.float32(v)) for v in (1.7, 0.1, 0.3))
+    y1, y8 = ops.decoupled_first_gemm(
+        x, e["packed"], w8, lam, w8s, alpha, beta, out_dtype=jnp.float32
+    )
+    unpadded = pack_signs(ref.unpack_ref(e["packed"])[:k])
+    r1, r8 = ref.decoupled_gemv_ref(x, unpadded, w8, lam, w8s, alpha, beta)
     assert np.abs(np.asarray(y1) - np.asarray(r1)).max() <= TOL
     assert np.abs(np.asarray(y8) - np.asarray(r8)).max() <= TOL
 

@@ -28,7 +28,7 @@ def _inputs(m, k, n, seed=0):
 
 W1A8_CASES = [
     # (m, k, n, bm, bk, bn)
-    (8, 16, 8, 8, 8, 8),
+    (8, 16, 8, 8, 16, 8),
     (8, 256, 128, 8, 128, 128),
     (128, 256, 256, 128, 256, 256),
     (64, 512, 128, 32, 256, 128),
@@ -48,7 +48,7 @@ def test_w1a8_vs_ref(m, k, n, bm, bk, bn):
 @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])
 def test_w1a8_out_dtypes(out_dtype):
     x, wp, gamma, lam = _inputs(16, 64, 32)
-    got = w1a8_matmul(x, wp, gamma, lam, bm=8, bk=32, bn=32,
+    got = w1a8_matmul(x, wp, gamma, lam, bm=8, bk=64, bn=32,
                       out_dtype=out_dtype, interpret=True)
     want = ref.w1a8_matmul_ref(x, wp, gamma, lam, out_dtype=out_dtype)
     assert got.dtype == out_dtype

@@ -437,7 +437,7 @@ class TestPagedReadWrite:
     B, MB, BS, H, D, NB = 2, 3, 4, 2, 8, 7
 
     def _pool_and_table(self):
-        pool = jnp.zeros((self.NB, self.BS, self.H, self.D), jnp.float32)
+        pool = jnp.zeros((self.NB, self.H, self.BS, self.D), jnp.float32)
         # slot 0 owns blocks [1, 2, 3]; slot 1 owns [4, 5, 6]
         table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
         return pool, table

@@ -20,8 +20,8 @@ def _setup(b, hkv, d, bs, mb, nb=None, dtype=jnp.float32, seed=0):
     not pool order."""
     rng = np.random.default_rng(seed)
     nb = nb or (b * mb + 3)
-    kpool = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), dtype)
-    vpool = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), dtype)
+    kpool = jnp.asarray(rng.standard_normal((nb, hkv, bs, d)), dtype)
+    vpool = jnp.asarray(rng.standard_normal((nb, hkv, bs, d)), dtype)
     table = jnp.asarray(
         rng.permutation(nb)[: b * mb].reshape(b, mb), jnp.int32
     )

@@ -189,11 +189,12 @@ class TestMultiDevice:
     agree with the unsharded kernels, and token streams match the
     single-device engines."""
 
-    def _run(self, code: str) -> dict:
+    def _run(self, code: str, **extra_env) -> dict:
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         env["PYTHONPATH"] = REPO_SRC
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # the forced host devices; never a TPU
+        env.update(extra_env)
         out = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(code)],
             capture_output=True, text=True, env=env, timeout=600,
@@ -280,3 +281,76 @@ class TestMultiDevice:
         assert res["cb_ok"], "2-device continuous engine tokens diverged"
         assert res["n_sharded"] > 0, "no weight leaf actually sharded"
         assert res["pool_sharded"] > 0, "no KV pool leaf actually sharded"
+
+    def test_two_device_paged_attention_island(self):
+        """The paged-attention kernel (interpret mode) run per KV-head
+        shard must be bitwise the unsharded kernel, in decode (T=1) and
+        chunk (T>1) form, and the continuous engine on the kernel path
+        must keep the single-device token streams."""
+        res = self._run("""
+            import json
+            import jax, jax.numpy as jnp, numpy as np
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from repro.configs.base import ModelConfig
+            from repro.core.quantization import QuantConfig
+            from repro.distributed import sharding as sh
+            from repro.kernels import ops
+            from repro.launch.mesh import make_host_mesh
+            from repro.models import api
+            from repro.serve.engine import SamplerConfig, serving_overrides
+            from repro.serve.scheduler import ContinuousBatchingEngine
+            from repro.train.quantized_serving import (
+                quantize_params_for_serving,
+            )
+
+            assert jax.device_count() == 2 and ops.paged_attention_enabled()
+            qc = QuantConfig(mode="pquant", r=16, num_experts=1)
+            cfg = ModelConfig(name="t", family="decoder", n_layers=2,
+                              d_model=32, n_heads=4, n_kv_heads=2, d_ff=48,
+                              vocab_size=64, quant=qc)
+            mesh = make_host_mesh(1, 2)
+            ov = serving_overrides(cfg, mesh)
+
+            # kernel island vs the unsharded kernel: 2 slots, 8 pages of 8
+            b, nb, bs, hkv, hq, d = 2, 8, 8, 2, 4, 16
+            ks = jax.random.split(jax.random.PRNGKey(4), 3)
+            kpool = jax.random.normal(ks[0], (nb, hkv, bs, d))
+            vpool = jax.random.normal(ks[1], (nb, hkv, bs, d))
+            table = jnp.asarray([[3, 0, 6, 1], [5, 2, 7, 4]], jnp.int32)
+            pool_sh = NamedSharding(mesh, P(None, "model", None, None))
+            island_ok = {}
+            for t in (1, 5):
+                q = jax.random.normal(ks[2], (b, t, hq, d))
+                start = jnp.asarray([20, 9], jnp.int32)
+                kv_lens = start + t
+                want = ops.paged_attention(q, kpool, vpool, table, start,
+                                           kv_lens)
+                with sh.sharding_rules(mesh, ov):
+                    got = jax.jit(ops.paged_attention)(
+                        q, jax.device_put(kpool, pool_sh),
+                        jax.device_put(vpool, pool_sh), table, start,
+                        kv_lens)
+                island_ok[t] = bool(np.array_equal(np.asarray(got),
+                                                   np.asarray(want)))
+
+            params, axes = api.init_model(jax.random.PRNGKey(1), cfg)
+            qp, _ = quantize_params_for_serving(params, axes, cfg,
+                                                packed=True)
+            scfg = SamplerConfig(temperature=0.0, max_new_tokens=6)
+            kw = dict(num_slots=2, max_len=32, scfg=scfg, layout="paged",
+                      block_size=8, chunk=4, prefill_chunk=3)
+            e0 = ContinuousBatchingEngine(qp, cfg, **kw)
+            e1 = ContinuousBatchingEngine(qp, cfg, mesh=mesh, **kw)
+            for e in (e0, e1):
+                for uid in range(2):
+                    prompt = np.asarray(jax.random.randint(
+                        jax.random.PRNGKey(7 + uid), (5 + 4 * uid,), 0, 64),
+                        np.int32)
+                    e.submit(prompt, max_new_tokens=6, seed=uid, uid=uid)
+            f0 = {f.uid: f.tokens for f in e0.run()}
+            f1 = {f.uid: f.tokens for f in e1.run()}
+            cb_ok = all(np.array_equal(f0[u], f1[u]) for u in f0)
+            print(json.dumps({"island_ok": island_ok, "cb_ok": bool(cb_ok)}))
+        """, REPRO_PAGED_ATTN="1")
+        assert res["island_ok"] == {"1": True, "5": True}, res
+        assert res["cb_ok"], "2-device kernel-path engine tokens diverged"
