@@ -11,7 +11,7 @@ and ``repro.kernels``.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Literal, Optional
 
 import jax
@@ -27,6 +27,27 @@ Array = jax.Array
 EPS = 1e-5
 
 INT8_QMAX = 127.0  # paper uses [-2^7, 2^7]; we clip to the representable 127
+
+# Named scopes of the fake-quant ops.  They change HLO metadata only (each
+# op's ``op_name``, backward ops included as ``transpose(jvp(quant/...))``),
+# never the program, so a profile's device time can be joined to weight and
+# activation quantization by scope.
+WEIGHT_SCOPE = "quant/weights"
+ACT_SCOPE = "quant/acts"
+
+
+def scoped(scope: str):
+    """Decorator: trace the function inside ``jax.named_scope(scope)``."""
+
+    def deco(fn):
+        @wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return deco
 
 
 # ---------------------------------------------------------------------------
@@ -75,6 +96,7 @@ def ste_sign(x: Array) -> Array:
 # ---------------------------------------------------------------------------
 
 
+@scoped(WEIGHT_SCOPE)
 def binarize_weights(w: Array) -> tuple[Array, Array]:
     """1-bit weight fake-quant (paper Eq. 3-6).
 
@@ -90,6 +112,7 @@ def binarize_weights(w: Array) -> tuple[Array, Array]:
     return signs * lam, lam
 
 
+@scoped(WEIGHT_SCOPE)
 def binarize_weights_grouped(w: Array, group_size: int) -> tuple[Array, Array]:
     """Group-wise 1-bit quantization (paper §4.6 ablation, groups of 64).
 
@@ -106,6 +129,7 @@ def binarize_weights_grouped(w: Array, group_size: int) -> tuple[Array, Array]:
     return (signs * lam).reshape(w.shape), lam.squeeze(-1)
 
 
+@scoped(WEIGHT_SCOPE)
 def binarize_weights_channelwise(w: Array) -> tuple[Array, Array]:
     """Channel-wise (per output column) 1-bit quantization (paper §4.6)."""
     mu = jnp.mean(w, axis=0, keepdims=True)
@@ -114,6 +138,7 @@ def binarize_weights_channelwise(w: Array) -> tuple[Array, Array]:
     return signs * lam, lam.squeeze(0)
 
 
+@scoped(WEIGHT_SCOPE)
 def binarize_weights_stacked(w: Array, n_batch_axes: int = 1) -> tuple[Array, Array]:
     """Per-slice 1-bit quantization for stacked (e.g. per-expert) weights.
 
@@ -127,6 +152,7 @@ def binarize_weights_stacked(w: Array, n_batch_axes: int = 1) -> tuple[Array, Ar
     return signs * lam, lam
 
 
+@scoped(WEIGHT_SCOPE)
 def ternarize_weights_stacked(w: Array, n_batch_axes: int = 1) -> tuple[Array, Array]:
     """Per-slice ternary quantization for stacked weights."""
     red = tuple(range(n_batch_axes, w.ndim))
@@ -135,6 +161,7 @@ def ternarize_weights_stacked(w: Array, n_batch_axes: int = 1) -> tuple[Array, A
     return q * lam, lam
 
 
+@scoped(WEIGHT_SCOPE)
 def quantize_weights_int8_stacked(w, n_batch_axes: int = 1) -> tuple[Array, Array]:
     """Per-slice INT8 AbsMax for stacked weights.  Accepts the serving dict
     layout ({"q": int8, "scale"}), in which case it dequantizes directly."""
@@ -158,6 +185,7 @@ def fake_quant_stacked(w, cfg: "QuantConfig", n_batch_axes: int = 1) -> Array:
     return binarize_weights_stacked(w, n_batch_axes)[0]
 
 
+@scoped(WEIGHT_SCOPE)
 def ternarize_weights(w: Array) -> tuple[Array, Array]:
     """BitNet-1.58 ternary {-1, 0, +1} AbsMean quantization (baseline).
 
@@ -168,6 +196,7 @@ def ternarize_weights(w: Array) -> tuple[Array, Array]:
     return q * lam, lam
 
 
+@scoped(WEIGHT_SCOPE)
 def quantize_weights_int8(w: Array, axis: Optional[int] = None) -> tuple[Array, Array]:
     """INT8 AbsMax weight fake-quant for the high-precision branch.
 
@@ -206,6 +235,7 @@ def act_scale_int8(x: Array) -> Array:
     return INT8_QMAX / (amax + EPS)
 
 
+@scoped(ACT_SCOPE)
 def quantize_activations_int8(x: Array) -> tuple[Array, Array]:
     """Per-token AbsMax INT8 activation fake-quant (paper Eq. 7-9).
 
@@ -224,6 +254,7 @@ def quantize_activations_int8(x: Array) -> tuple[Array, Array]:
     return (q / gamma).astype(x.dtype), gamma
 
 
+@scoped(ACT_SCOPE)
 def quantize_act_int8(x: Array) -> tuple[Array, Array]:
     """Per-token AbsMax INT8 (runtime, true-integer path).
 

@@ -22,6 +22,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core.quantization import WEIGHT_SCOPE, scoped
 from repro.distributed.sharding import shard_hint
 
 Array = jax.Array
@@ -45,6 +46,7 @@ def binarize_gather(w: Array, axes: tuple) -> Array:
     return y
 
 
+@scoped(WEIGHT_SCOPE)
 def _fwd(w: Array, axes: tuple):
     mu = jnp.mean(w)
     lam = jnp.mean(jnp.abs(w)) + EPS
@@ -79,6 +81,7 @@ def binarize_gather_stacked(w: Array, axes: tuple) -> Array:
     return y
 
 
+@scoped(WEIGHT_SCOPE)
 def _fwd_stacked(w: Array, axes: tuple):
     red = tuple(range(max(0, w.ndim - 2), w.ndim))
     mu = jnp.mean(w, axis=red, keepdims=True)
@@ -109,6 +112,7 @@ def int8_gather(w: Array, axes: tuple) -> Array:
     return y
 
 
+@scoped(WEIGHT_SCOPE)
 def _fwd8(w: Array, axes: tuple):
     amax = jnp.max(jnp.abs(w)) + EPS
     scale = 127.0 / amax

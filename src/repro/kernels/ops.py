@@ -92,12 +92,12 @@ _BN_CANDIDATES = (512, 256, 128)
 
 
 def _annotate(name: str):
-    """Profiler span (``repro.serve.tracing.annotate``) around a kernel
+    """Profiler span (``repro.telemetry.tracing.annotate``) around a kernel
     dispatch site — host-timeline TraceAnnotation + named_scope so kernel
     time is attributable by name in a profiler trace.  Imported lazily:
-    the kernel tier stays importable without the serving layer, and the
+    the kernel tier stays importable without the telemetry layer, and the
     context manager runs at trace time, never per decode step."""
-    from repro.serve.tracing import annotate
+    from repro.telemetry.tracing import annotate
 
     return annotate(name)
 

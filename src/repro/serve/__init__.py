@@ -226,9 +226,10 @@ threaded through the whole stack:
   ``jax.profiler.TraceAnnotation`` + ``named_scope``; the annotations
   are applied unconditionally, so enabling or disabling metrics/tracing
   changes NO compiled program — byte-identical lowering is asserted in
-  ``tests/test_metrics.py``.  Setting ``REPRO_PROFILE_DIR=/path`` wraps
-  engine runs in ``jax.profiler.start_trace``/``stop_trace`` for a
-  loadable device profile.
+  ``tests/test_metrics.py``.  Every engine step is a span tree
+  (``serve/step`` and its phases, listed in ``repro.serve.scheduler``);
+  ``jax.profiler.trace(dir)`` around any region captures a device
+  profile in which those spans attribute the device's idle time.
 
 Clocks: ``clock=None`` keeps the deterministic virtual clock (one tick
 per decode chunk); any ``now()`` callable or a
@@ -271,5 +272,4 @@ from repro.serve.tracing import (  # noqa: F401
     ListSink,
     RequestTracer,
     annotate,
-    maybe_profile,
 )

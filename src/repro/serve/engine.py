@@ -38,7 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig
 from repro.distributed import sharding as shd
 from repro.models import api
-from repro.serve.tracing import annotate, maybe_profile
+from repro.serve.tracing import annotate
 
 Array = jax.Array
 
@@ -396,7 +396,7 @@ class DecodeEngine:
                 f"max_new_tokens must be >= 1, got {scfg.max_new_tokens}"
             )
         batch, pos_off = self._batch_and_off(prompts, extra_inputs)
-        with maybe_profile("decode_engine_generate"), self._mesh_ctx():
+        with self._mesh_ctx():
             toks = self._gen_fn(scfg)(
                 self.params, batch, pos_off, jax.random.PRNGKey(seed)
             )

@@ -85,13 +85,40 @@ Robustness contract (the failure story every later scale PR inherits):
   deterministically force allocator failures, preemptions, poisoned
   logits and delayed arrivals through no-op-by-default hooks; disabled,
   the compiled programs are byte-identical to the fault-free build.
+
+Step anatomy (observability): every ``step()`` is a tree of profiler spans
+(:func:`~repro.telemetry.tracing.annotate`, on the profiler's own clock,
+so a device trace attributes each idle gap to the host phase it fell in)::
+
+    serve/step
+      serve/admit            admission: blocks, tables, one-shot prefill
+        serve/prefix_cow     copy-on-write of a fully cached prompt's page
+        serve/admission_prefill  one-shot or cached prefill dispatch
+        serve/prefill_fetch  its first token
+      serve/chunked_prefill  one prompt slice (dispatch, bookkeeping)
+        serve/prefill_fetch  first token, after a prompt's last slice
+      serve/wait_arrival     nothing in flight: waiting for an arrival
+      serve/ensure_blocks    pool blocks for the coming chunk
+      serve/decode_chunk
+        serve/decode_dispatch  the compiled chunk's dispatch
+        serve/decode_fetch     the blocking fetch of its tokens
+      serve/process_chunk    the host mirror and evictions
+
+With a tracer attached each step also emits one ``step`` event (its work,
+queue, pool, preemptions, program traces and per-span seconds), and every
+compiled program counts its traces on ``program_traces_total{program=}``:
+a retrace names its program and lands on its step.  Capture a profile with
+``jax.profiler.trace(dir)`` around any region.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import logging
+import time
 from typing import Callable, Optional
 
 import jax
@@ -113,8 +140,8 @@ from repro.serve.engine import (
     serving_overrides,
 )
 from repro.serve.faults import FaultInjector
-from repro.serve.metrics import MetricsRegistry, resolve_clock
-from repro.serve.tracing import RequestTracer, annotate, maybe_profile
+from repro.serve.metrics import Counter, MetricsRegistry, resolve_clock
+from repro.serve.tracing import RequestTracer, annotate
 
 Array = jax.Array
 
@@ -682,9 +709,10 @@ class ContinuousBatchingEngine:
     tracer : optional :class:`repro.serve.tracing.RequestTracer`; when set
         every request's lifecycle (submitted -> admitted -> prefill ->
         first_token -> decode -> finished(reason)), block alloc/free,
-        preemptions and fired faults are emitted as structured events on
-        the engine clock.  May also be attached later (``eng.tracer =
-        ...``) — benches attach after warm-up.
+        preemptions, fired faults and one ``step`` event per engine step
+        are emitted as structured events on the engine clock.  May also
+        be attached later (``eng.tracer = ...``) — benches attach after
+        warm-up.
     max_queue : bound on the admission queue (``None`` = unbounded).  A
         submit into a full queue invokes ``overload_policy`` and the loser
         finishes with reason ``"shed"`` — backpressure is explicit, not an
@@ -805,6 +833,11 @@ class ContinuousBatchingEngine:
         self._m_pc_misses = m.counter("prefix_cache_misses_total")
         self._m_pc_hit_tokens = m.counter("prefix_cache_hit_tokens_total")
         self._m_pc_cow = m.counter("prefix_cache_cow_total")
+        # program_traces_total{program=...}, filled by _jit
+        self._m_traces: dict[str, Counter] = {}
+        # per-span seconds of the current step (``_span``)
+        self._phase_s: dict[str, float] = {}
+        self._step_decoding = self._step_decode_tokens = 0
         m.register_collector(_tile_cache_stats)
         self.allocator = (
             kv_pool.BlockAllocator(
@@ -883,11 +916,13 @@ class ContinuousBatchingEngine:
         # the cold path's)
         self._suffix_fns: dict[int, Callable] = {}
         self._copy_block_fn = (
-            jax.jit(_make_copy_block_fn(cfg), donate_argnums=(0,))
+            self._jit("copy_block", _make_copy_block_fn(cfg),
+                      donate_argnums=(0,))
             if self.prefix_cache else None
         )
         self._prefill_chunk = (
-            jax.jit(
+            self._jit(
+                "prefill_chunk",
                 _make_prefill_chunk_fn(cfg, self.scfg, self.prefill_chunk),
                 donate_argnums=(1,),
             )
@@ -898,27 +933,66 @@ class ContinuousBatchingEngine:
         # admission right-pads prompts to power-of-two buckets so one
         # trace covers a whole bucket.  Both return packed [tok, valid]
         # so prefill quarantine rides the admission fetch.
-        self._prefill = jax.jit(
-            _make_checked_prefill_fn(cfg, max_len, self.scfg)
+        self._prefill = self._jit(
+            "prefill", _make_checked_prefill_fn(cfg, max_len, self.scfg)
         )
         self._prefill_bucketed = (
-            jax.jit(_make_bucketed_prefill_fn(cfg, max_len, self.scfg))
+            self._jit("prefill_bucketed",
+                      _make_bucketed_prefill_fn(cfg, max_len, self.scfg))
             if _bucketed_prefill_safe(cfg, max_len) else None
         )
         # the cache tree and slot state are donated: the chunk rewrites
         # them in place instead of copying the full KV pool every chunk
         # (the caller rebinds both from the return value)
-        self._chunk_fn = jax.jit(
-            _make_cb_chunk_fn(cfg, self.scfg, chunk), donate_argnums=(1, 2)
+        self._chunk_fn = self._jit(
+            "chunk", _make_cb_chunk_fn(cfg, self.scfg, chunk),
+            donate_argnums=(1, 2),
         )
         # fault-injection variant (extra poison-step operand): compiled
         # lazily and only when a FaultInjector schedules a logit poison,
         # so the fault-free build never traces it
         self._chunk_fn_poison: Optional[Callable] = None
         self._install_fns: dict[int, Callable] = {}
-        self._set_tables = jax.jit(_make_set_tables_fn(cfg), donate_argnums=(0,))
-        self._admit_jit = jax.jit(_admit_state, donate_argnums=(0,))
-        self._deactivate_jit = jax.jit(_deactivate, donate_argnums=(0,))
+        self._set_tables = self._jit(
+            "set_tables", _make_set_tables_fn(cfg), donate_argnums=(0,)
+        )
+        self._admit_jit = self._jit("admit", _admit_state,
+                                    donate_argnums=(0,))
+        self._deactivate_jit = self._jit("deactivate", _deactivate,
+                                         donate_argnums=(0,))
+
+    def _jit(self, program: str, fn: Callable, **kw) -> Callable:
+        """``jax.jit(fn, **kw)`` whose Python body counts each trace on
+        ``program_traces_total{program=...}``.  The count runs at trace
+        time only, so it costs nothing once compiled and never changes
+        the lowered program (``functools.wraps`` keeps its name)."""
+        traces = self._m_traces.get(program)
+        if traces is None:
+            traces = self.metrics.counter("program_traces_total",
+                                          program=program)
+            self._m_traces[program] = traces
+
+        @functools.wraps(fn)
+        def counted(*args, **kwargs):
+            traces.inc()
+            return fn(*args, **kwargs)
+
+        return jax.jit(counted, **kw)
+
+    def _traces_total(self) -> int:
+        return int(sum(c.value for c in self._m_traces.values()))
+
+    @contextlib.contextmanager
+    def _span(self, name: str):
+        """Profiler span ``name`` (:func:`annotate`) whose seconds also add
+        up in the current step's ``phase_s`` (``time.perf_counter``)."""
+        t = time.perf_counter()
+        try:
+            with annotate(name):
+                yield
+        finally:
+            self._phase_s[name] = (self._phase_s.get(name, 0.0)
+                                   + time.perf_counter() - t)
 
     def _mesh_ctx(self):
         """Serving sharding rules, active around every compiled-fn call
@@ -1051,9 +1125,13 @@ class ContinuousBatchingEngine:
 
     # -- host boundary ------------------------------------------------------
 
-    def _fetch(self, x) -> np.ndarray:
+    def _fetch(self, x, span: str) -> np.ndarray:
+        """The blocking device->host transfer, under the span ``span``
+        (``serve/decode_fetch`` or ``serve/prefill_fetch``): waiting on the
+        device reads apart from host work in a profile and a step event."""
         self.host_transfers += 1
-        return np.asarray(x)
+        with self._span(span):
+            return np.asarray(x)
 
     def now(self) -> float:
         return self._clock() if self._clock is not None else self._now
@@ -1161,13 +1239,10 @@ class ContinuousBatchingEngine:
 
     def run(self) -> list[FinishedRequest]:
         """Process the queue to completion; FinishedRequests in completion
-        order.  With ``REPRO_PROFILE_DIR`` set the whole run is bracketed
-        by ``jax.profiler.start_trace/stop_trace`` (see
-        :func:`repro.serve.tracing.maybe_profile`)."""
+        order."""
         finished: list[FinishedRequest] = []
-        with maybe_profile("serve_run"):
-            while self._queue or self._live() or self._pending_finished:
-                finished.extend(self.step())
+        while self._queue or self._live() or self._pending_finished:
+            finished.extend(self.step())
         return finished
 
     def step(self) -> list[FinishedRequest]:
@@ -1183,9 +1258,18 @@ class ContinuousBatchingEngine:
         advances no prefill while work is ready (live slots, or an
         arrived queued request) counts toward ``watchdog_steps``;
         exceeding it raises :class:`SchedulerStall` with the full
-        scheduler state in the message instead of spinning forever."""
+        scheduler state in the message instead of spinning forever.
+
+        The step runs under the span ``serve/step``; with a tracer
+        attached it ends with one ``step`` event (:meth:`_emit_step`)."""
+        t0 = time.perf_counter()
+        idx = self._step_idx
         before = (self.tokens_generated, self.prefill_tokens)
-        finished = self._step_body()
+        counts0 = (self.preemptions, self._traces_total())
+        self._phase_s = {}
+        self._step_decoding = self._step_decode_tokens = 0
+        with annotate("serve/step"):
+            finished = self._step_body()
         self._step_idx += 1
         self._m_steps.inc()
         self._m_queue_depth.set(len(self._queue))
@@ -1193,6 +1277,8 @@ class ContinuousBatchingEngine:
         progressed = bool(finished) or (
             (self.tokens_generated, self.prefill_tokens) != before
         )
+        if self.tracer is not None:
+            self._emit_step(idx, before[1], counts0, t0)
         now = self.now()
         work_ready = bool(self._live()) or any(
             r.arrival <= now for r in self._queue
@@ -1207,11 +1293,36 @@ class ContinuousBatchingEngine:
                 raise SchedulerStall(report)
         return finished
 
+    def _emit_step(self, idx: int, prefill0: int, counts0: tuple,
+                   t0: float) -> None:
+        """The ``step`` event: what step ``idx`` did (prompt tokens
+        written, decode tokens and decoding slots), what it left (live
+        slots, queue, pool blocks in use), preemptions and program traces
+        during it, its wall and fetch seconds, and the inclusive seconds
+        of each span it ran (``phase_s``)."""
+        phases = {k: round(v, 6) for k, v in self._phase_s.items()}
+        self._trace(
+            "step", step=idx,
+            prefill_rows=self.prefill_tokens - prefill0,
+            decode_tokens=self._step_decode_tokens,
+            n_decoding=self._step_decoding,
+            n_live=len(self._live()), queue_depth=len(self._queue),
+            blocks_used=(self.allocator.used_count
+                         if self.allocator is not None else None),
+            preempted=self.preemptions - counts0[0],
+            traces=self._traces_total() - counts0[1],
+            wall_s=time.perf_counter() - t0,
+            fetch_s=sum(v for k, v in self._phase_s.items()
+                        if k.endswith("_fetch")),
+            phase_s=phases,
+        )
+
     def _step_body(self) -> list[FinishedRequest]:
         finished = self._drain_pending()
         finished.extend(self._expire_deadlines())
         self._injected_preemptions()
-        finished.extend(self._admit_arrived())
+        with self._span("serve/admit"):
+            finished.extend(self._admit_arrived())
         finished.extend(self._prefill_tick())
         if not any(rs.n_generated > 0 for rs in self._live()):
             if self._live():
@@ -1223,16 +1334,27 @@ class ContinuousBatchingEngine:
                 self._advance_clock()
             return finished
         if self.allocator is not None:
-            self._ensure_blocks()
-        with annotate("serve/decode_chunk"):
-            packed = self._fetch(self._run_chunk())
+            with self._span("serve/ensure_blocks"):
+                self._ensure_blocks()
+        self._step_decoding = sum(
+            1 for rs in self._live() if rs.n_generated > 0
+        )
+        with self._span("serve/decode_chunk"):
+            with self._span("serve/decode_dispatch"):
+                out = self._run_chunk()
+            packed = self._fetch(out, "serve/decode_fetch")
         if self._clock is None:
             self._now += 1.0
         self._trace(
             "decode_chunk", step=self._step_idx,
-            n_decoding=sum(1 for rs in self._live() if rs.n_generated > 0),
+            n_decoding=self._step_decoding,
+            blocks_used=(self.allocator.used_count
+                         if self.allocator is not None else None),
         )
-        finished.extend(self._process_chunk(packed))
+        tokens0 = self.tokens_generated
+        with self._span("serve/process_chunk"):
+            finished.extend(self._process_chunk(packed))
+        self._step_decode_tokens = self.tokens_generated - tokens0
         return finished
 
     def _drain_pending(self) -> list[FinishedRequest]:
@@ -1340,7 +1462,8 @@ class ContinuousBatchingEngine:
             # the clock's own sleep (resolve_clock): a ManualClock test
             # advances virtual time here instead of really sleeping, so
             # deadline math, traces and waiting share one timeline
-            self._sleep(max(0.0, min(nxt - self.now(), 0.05)))
+            with self._span("serve/wait_arrival"):
+                self._sleep(max(0.0, min(nxt - self.now(), 0.05)))
 
     def _admit_arrived(self) -> list[FinishedRequest]:
         """FIFO-admit every arrived request that fits a free slot (and, if
@@ -1538,6 +1661,11 @@ class ContinuousBatchingEngine:
         if not pending:
             return []
         rs = min(pending, key=lambda r: (r.admitted_at, r.slot))
+        with self._span("serve/chunked_prefill"):
+            return self._prefill_slice(rs)
+
+    def _prefill_slice(self, rs: RequestState) -> list[FinishedRequest]:
+        """One slice of ``rs``'s prompt (see :meth:`_prefill_tick`)."""
         t = self.prefill_chunk
         req = rs.request
         s = len(req.prompt)
@@ -1551,7 +1679,7 @@ class ContinuousBatchingEngine:
         active[rs.slot] = True
         lengths = np.zeros((b,), np.int32)
         lengths[rs.slot] = n
-        with annotate("serve/chunked_prefill"), self._mesh_ctx():
+        with self._mesh_ctx():
             tok_d, self._caches, key_d = self._prefill_chunk(
                 self.params, self._caches, jnp.asarray(toks),
                 jnp.asarray(pos), jnp.asarray(active), jnp.asarray(lengths),
@@ -1570,7 +1698,7 @@ class ContinuousBatchingEngine:
             return []
         # one packed [tok0, finite] fetch per admission — validity rides
         # the transfer that was already happening
-        arr = self._fetch(tok_d)
+        arr = self._fetch(tok_d, "serve/prefill_fetch")
         tok0, ok = int(arr[0]), bool(arr[1])
         now = self.now()
         if not ok:
@@ -1629,7 +1757,8 @@ class ContinuousBatchingEngine:
         (lazily jitted; one trace per power-of-two suffix length)."""
         fn = self._suffix_fns.get(t)
         if fn is None:
-            fn = jax.jit(
+            fn = self._jit(
+                "suffix_prefill",
                 _make_prefill_chunk_fn(self.cfg, self.scfg, t),
                 donate_argnums=(1,),
             )
@@ -1672,7 +1801,7 @@ class ContinuousBatchingEngine:
             )
         self.prefill_tokens += n
         # one packed [tok0, finite] fetch per admission
-        arr = self._fetch(tok_d)
+        arr = self._fetch(tok_d, "serve/prefill_fetch")
         tok0, ok = int(arr[0]), bool(arr[1])
         now = self.now()
         if not ok:
@@ -1732,7 +1861,7 @@ class ContinuousBatchingEngine:
         with annotate("serve/admission_prefill"):
             tok0_d, small, pos0, key = self._admission_prefill(req)
         # one packed [tok0, finite] fetch per admission
-        arr = self._fetch(tok0_d)
+        arr = self._fetch(tok0_d, "serve/prefill_fetch")
         tok0, ok = int(arr[0]), bool(arr[1])
         now = self.now()
         if not ok:
@@ -1752,8 +1881,9 @@ class ContinuousBatchingEngine:
         table_row = self._table_row(blocks)
         nb = len(blocks)
         if nb not in self._install_fns:
-            self._install_fns[nb] = jax.jit(
-                _make_install_fn(self.cfg, nb), donate_argnums=(0,)
+            self._install_fns[nb] = self._jit(
+                "install", _make_install_fn(self.cfg, nb),
+                donate_argnums=(0,),
             )
         with self._mesh_ctx():
             self._caches = self._install_fns[nb](
@@ -1867,7 +1997,8 @@ class ContinuousBatchingEngine:
                 poison = jnp.asarray(spec)
         if poison is not None:
             if self._chunk_fn_poison is None:
-                self._chunk_fn_poison = jax.jit(
+                self._chunk_fn_poison = self._jit(
+                    "chunk_poison",
                     _make_cb_chunk_fn(
                         self.cfg, self.scfg, self.chunk, poison=True
                     ),

@@ -3,12 +3,10 @@
 with the serving stack.  Serving-side imports keep working unchanged."""
 
 from repro.telemetry.tracing import (  # noqa: F401
-    PROFILE_DIR_ENV,
     JsonlSink,
     ListSink,
     RequestTracer,
     TrainTracer,
     annotate,
     fault_hook,
-    maybe_profile,
 )

@@ -13,9 +13,9 @@ Layout
 ------
 ``metrics``   Counter / Gauge / fixed-bucket Histogram, MetricsRegistry
               (snapshot + Prometheus text), validate_snapshot, clocks.
-``tracing``   annotate (profiler spans), maybe_profile (REPRO_PROFILE_DIR
-              capture), JsonlSink/ListSink, RequestTracer (serving
-              lifecycle), TrainTracer (training lifecycle).
+``tracing``   annotate (profiler spans), JsonlSink/ListSink, RequestTracer
+              (serving lifecycle), TrainTracer (training lifecycle).
+              Capture a profile with ``jax.profiler.trace(dir)``.
 ``probes``    On-device QAT health probes: an ambient collector that
               forward-pass tap sites record into, scan-boundary helpers,
               the param-side probe computations and the cadenced
@@ -34,6 +34,12 @@ Serving (wired by the engines / scheduler / kv_pool — see PR 7/9):
   ``prefix_cache_hits_total`` / ``prefix_cache_misses_total`` /
   ``prefix_cache_hit_tokens_total`` / ``prefix_cache_cow_total`` /
   ``prefix_cache_evictions_total``
+  ``preemptions_total``
+  ``program_traces_total{program=...}``  traces of each compiled engine
+                            program (``chunk``, ``prefill_chunk``,
+                            ``prefill``, ``install``, ``set_tables``, ...),
+                            counted in the program's Python body, so only
+                            at trace time: a retrace names its program
 
 Training (wired by ``repro.train.trainer.Trainer``):
   counters   ``train_steps_total``, ``train_recoveries_total``,
@@ -104,6 +110,30 @@ signal — democratization is being broken), and ``qat_clip_act`` low;
 spikes in ``qat_scale_drift_*`` precede the loss spikes that trigger
 ``recovery`` events (paper Fig. 10).
 
+Reading a serving trace
+-----------------------
+With a ``RequestTracer`` attached the engine emits, besides the request
+lifecycle (``submitted``, ``block_alloc``, ``admitted``, ``prefill_chunk``,
+``first_token``, ``decode_chunk``, ``finished``, ``block_free``,
+``preempted``, ``prefix_hit``, ``block_cow``, ``stall``, ``fault_*``),
+one ``step`` event per engine step on the engine clock:
+
+  ``step``, ``prefill_rows`` (prompt tokens written), ``decode_tokens``,
+  ``n_decoding``, ``n_live``, ``queue_depth``, ``blocks_used`` (paged
+  only), ``preempted`` and ``traces`` (during the step), ``wall_s`` and
+  ``fetch_s`` (``time.perf_counter`` seconds, real even under the virtual
+  clock), ``phase_s`` ({span name: inclusive seconds}).
+
+``decode_chunk`` events carry ``n_decoding`` and ``blocks_used`` (pool
+blocks held while the chunk ran).  Profiler spans of a step (host
+timeline, the device trace's clock): ``serve/step`` > ``serve/admit``,
+``serve/chunked_prefill``, ``serve/wait_arrival``,
+``serve/ensure_blocks``, ``serve/decode_chunk`` (> ``serve/decode_dispatch``,
+``serve/decode_fetch``), ``serve/process_chunk``; ``serve/prefill_fetch``
+under admission or the prompt's last slice.  Fake-quant ops carry the
+named scopes ``quant/weights`` and ``quant/acts`` in HLO metadata
+(``repro.core.quantization``).
+
 The invariant that makes all of this free: with telemetry disabled
 (``probes=False``, no tracer/registry attached), ``train_step`` lowers to
 a byte-identical program — pinned by ``tests/test_train_telemetry.py``,
@@ -122,12 +152,10 @@ from repro.telemetry.metrics import (  # noqa: F401
     validate_snapshot,
 )
 from repro.telemetry.tracing import (  # noqa: F401
-    PROFILE_DIR_ENV,
     JsonlSink,
     ListSink,
     RequestTracer,
     TrainTracer,
     annotate,
     fault_hook,
-    maybe_profile,
 )

@@ -3,7 +3,7 @@
 (Originally ``repro.serve.tracing``, PR 7; promoted here so training and
 serving trace through one core.  The serving module re-exports.)
 
-Four layers, all zero-overhead when disabled:
+Three layers, all zero-overhead when disabled:
 
 1. **Request lifecycle tracing** — :class:`RequestTracer` turns every
    request's life into an ordered span record::
@@ -11,11 +11,12 @@ Four layers, all zero-overhead when disabled:
        submitted -> admitted -> prefill_chunk* -> first_token ->
        decode_chunk* -> finished(reason)
 
-   plus block-alloc/free events, preemptions, fired faults and the
+   plus block-alloc/free events, preemptions, fired faults, the
    prefix-cache lifecycle (``prefix_hit`` when an admission walk reuses
    cached blocks — with ``n_blocks``/``n_tokens`` — and ``block_cow``
    when a fully-cached prompt copies its final shared page before
-   diverging), each a flat JSON-serialisable dict ``{"t": ...,
+   diverging) and one ``step`` event per engine step, each a
+   JSON-serialisable dict ``{"t": ...,
    "event": ..., "uid": ..., **fields}`` pushed through a pluggable sink (:class:`JsonlSink` for
    structured JSONL on disk, :class:`ListSink` for in-memory assertions).
    Timestamps come from the ENGINE's clock — the same ``now()`` that
@@ -34,14 +35,12 @@ Four layers, all zero-overhead when disabled:
    programs, only metadata, and it is applied unconditionally so
    enabling/disabling metrics cannot perturb compiled programs.
 
-3. **Trace capture** — :func:`maybe_profile` brackets a region with
-   ``jax.profiler.start_trace`` / ``stop_trace`` when the opt-in
-   ``REPRO_PROFILE_DIR`` env var is set (no-op otherwise), giving a
-   TensorBoard-loadable trace where the :func:`annotate` names attribute
-   prefill / decode / kernel time.  Re-entrant (inner brackets no-op) and
-   best-effort: a broken profiler must never break serving.
+   To capture, bracket any region with ``jax.profiler.trace(dir)``: the
+   spans land in the same ``.xplane.pb`` as the device ops, on one clock.
+   The serving engine's step spans are listed in
+   ``repro.serve.scheduler``'s module docstring.
 
-4. **Training lifecycle tracing** — :class:`TrainTracer` is the Trainer's
+3. **Training lifecycle tracing** — :class:`TrainTracer` is the Trainer's
    counterpart to :class:`RequestTracer`: per-step records plus
    checkpoint / restore / recovery / heartbeat events through the same
    sinks, self-clocked (run-relative seconds) because a training run has
@@ -53,17 +52,10 @@ from __future__ import annotations
 
 import contextlib
 import json
-import logging
 import os
 from typing import IO, Callable, Optional, Union
 
 import jax
-
-_log = logging.getLogger(__name__)
-
-#: Opt-in profiler env var: set to a directory to capture a
-#: TensorBoard-readable trace of engine runs.
-PROFILE_DIR_ENV = "REPRO_PROFILE_DIR"
 
 
 @contextlib.contextmanager
@@ -75,43 +67,6 @@ def annotate(name: str):
     untouched."""
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
-
-
-# start_trace is process-global and errors when nested: engine runs can
-# nest (a CB engine warms itself with an inner run), so the outermost
-# bracket wins and inner ones no-op.
-_PROFILING = False
-
-
-@contextlib.contextmanager
-def maybe_profile(tag: str = "serve"):
-    """Bracket a region with ``jax.profiler.start_trace/stop_trace`` into
-    ``$REPRO_PROFILE_DIR`` when that env var is set; otherwise (or when a
-    bracket is already active) a no-op.  Best-effort by design: profiling
-    failures are logged once and swallowed — observability must never
-    take serving down."""
-    global _PROFILING
-    out = os.environ.get(PROFILE_DIR_ENV)
-    if not out or _PROFILING:
-        yield
-        return
-    started = False
-    try:
-        jax.profiler.start_trace(out)
-        started = True
-    except Exception as e:  # noqa: BLE001 — profiler breakage must not break serving
-        _log.warning("profiler start_trace(%s) failed for %s: %s", out, tag, e)
-    _PROFILING = started or _PROFILING
-    try:
-        with annotate(f"repro/{tag}"):
-            yield
-    finally:
-        if started:
-            _PROFILING = False
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:  # noqa: BLE001
-                _log.warning("profiler stop_trace failed for %s: %s", tag, e)
 
 
 # ---------------------------------------------------------------------------

@@ -34,7 +34,7 @@ from repro.optim.adamw import (
 from repro.optim.schedule import schedule_for_mode
 from repro.telemetry import probes as qprobes
 from repro.telemetry.metrics import MetricsRegistry
-from repro.telemetry.tracing import JsonlSink, TrainTracer, annotate, maybe_profile
+from repro.telemetry.tracing import JsonlSink, TrainTracer, annotate
 
 Array = jax.Array
 
@@ -251,8 +251,9 @@ class Trainer:
     * console output goes through ``logging`` (logger ``repro.train``):
       the human one-liner at ``log_every`` on INFO, a structured JSON
       record per step on DEBUG.
-    * ``REPRO_PROFILE_DIR`` captures a profiler trace of :meth:`run` with
-      ``train/grads`` / ``train/accum`` / ``train/update`` annotations.
+    * ``train/grads`` / ``train/accum`` / ``train/update`` profiler
+      annotations: capture them with ``jax.profiler.trace(dir)`` around
+      :meth:`run`.
 
     All of it detaches cleanly: no registry/tracer and ``probes=False``
     reproduce the bare loop, with ``train_step`` lowering byte-identical.
@@ -349,69 +350,68 @@ class Trainer:
             )
         t_last = time.time()
         try:
-            with maybe_profile("train"):
-                for step, batch in self.data:
-                    if step < self.start_step:
-                        continue
-                    if step >= tcfg.total_steps:
-                        break
-                    jb = {k: jnp.asarray(v) for k, v in batch.items()}
-                    t0 = time.time()
-                    self.state, metrics = self.step_fn(self.state, jb)
-                    loss = float(metrics["loss"])  # the one host sync
-                    dt_step = time.time() - t0
-                    if not np.isfinite(loss) and tcfg.auto_recover and self.ckpt:
-                        # fault path: reload last good ckpt (paper Fig. 10)
-                        # — recorded, not silent: the history/trace carry
-                        # (step, restored-from step, running count)
-                        self.recoveries += 1
-                        self._restore()
-                        self.metrics.counter("train_recoveries_total").inc()
-                        rec = {
-                            "step": step, "event": "recovery", "loss": loss,
-                            "from_step": self.start_step,
-                            "recoveries": self.recoveries,
-                        }
-                        self._record(rec, hist_f)
-                        _log.warning(
-                            "step %d: non-finite loss, restored from step %d "
-                            "(recovery #%d)",
-                            step, self.start_step, self.recoveries,
-                        )
-                        continue
-                    rec = {k: float(v) for k, v in metrics.items()}
-                    rec["step"] = step
-                    rec["step_time_s"] = dt_step
-                    if (
-                        tcfg.sensitivity_every > 0
-                        and step % tcfg.sensitivity_every == 0
-                    ):
-                        # cadenced democratization snapshot — host-side,
-                        # off the jit path (repro.telemetry.probes)
-                        rec.update(
-                            qprobes.sensitivity_snapshot(self.state.params)
-                        )
+            for step, batch in self.data:
+                if step < self.start_step:
+                    continue
+                if step >= tcfg.total_steps:
+                    break
+                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                t0 = time.time()
+                self.state, metrics = self.step_fn(self.state, jb)
+                loss = float(metrics["loss"])  # the one host sync
+                dt_step = time.time() - t0
+                if not np.isfinite(loss) and tcfg.auto_recover and self.ckpt:
+                    # fault path: reload last good ckpt (paper Fig. 10)
+                    # — recorded, not silent: the history/trace carry
+                    # (step, restored-from step, running count)
+                    self.recoveries += 1
+                    self._restore()
+                    self.metrics.counter("train_recoveries_total").inc()
+                    rec = {
+                        "step": step, "event": "recovery", "loss": loss,
+                        "from_step": self.start_step,
+                        "recoveries": self.recoveries,
+                    }
                     self._record(rec, hist_f)
-                    steps_total.inc()
-                    step_seconds.observe(dt_step)
-                    self._gauges(rec)
-                    if tcfg.heartbeat_path:
-                        _write_atomic(tcfg.heartbeat_path, str(step))
-                    if step % tcfg.log_every == 0:
-                        dt = time.time() - t_last
-                        t_last = time.time()
-                        _log.info(
-                            "step %5d loss %.4f nll %.4f lr %.2e gnorm %.2f "
-                            "(%.1fs)", step, rec["loss"], rec["nll"],
-                            rec["lr"], rec["grad_norm"], dt,
-                        )
-                        if self.tracer:
-                            self.tracer.emit("heartbeat", step=step)
-                    if self.ckpt and step > 0 and step % tcfg.ckpt_every == 0:
-                        self.ckpt.save(step, self.state._asdict())
-                        self.metrics.counter("train_checkpoints_total").inc()
-                        if self.tracer:
-                            self.tracer.emit("checkpoint", step=step)
+                    _log.warning(
+                        "step %d: non-finite loss, restored from step %d "
+                        "(recovery #%d)",
+                        step, self.start_step, self.recoveries,
+                    )
+                    continue
+                rec = {k: float(v) for k, v in metrics.items()}
+                rec["step"] = step
+                rec["step_time_s"] = dt_step
+                if (
+                    tcfg.sensitivity_every > 0
+                    and step % tcfg.sensitivity_every == 0
+                ):
+                    # cadenced democratization snapshot — host-side,
+                    # off the jit path (repro.telemetry.probes)
+                    rec.update(
+                        qprobes.sensitivity_snapshot(self.state.params)
+                    )
+                self._record(rec, hist_f)
+                steps_total.inc()
+                step_seconds.observe(dt_step)
+                self._gauges(rec)
+                if tcfg.heartbeat_path:
+                    _write_atomic(tcfg.heartbeat_path, str(step))
+                if step % tcfg.log_every == 0:
+                    dt = time.time() - t_last
+                    t_last = time.time()
+                    _log.info(
+                        "step %5d loss %.4f nll %.4f lr %.2e gnorm %.2f "
+                        "(%.1fs)", step, rec["loss"], rec["nll"],
+                        rec["lr"], rec["grad_norm"], dt,
+                    )
+                    if self.tracer:
+                        self.tracer.emit("heartbeat", step=step)
+                if self.ckpt and step > 0 and step % tcfg.ckpt_every == 0:
+                    self.ckpt.save(step, self.state._asdict())
+                    self.metrics.counter("train_checkpoints_total").inc()
+                    if self.tracer:
+                        self.tracer.emit("checkpoint", step=step)
             if self.ckpt:
                 final = int(self.state.opt.step)
                 self.ckpt.save(final, self.state._asdict())

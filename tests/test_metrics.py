@@ -2,7 +2,8 @@
 bounds, snapshot schema, Prometheus export), request-trace span ordering,
 clock injection (ManualClock drives the engine with zero real sleeps),
 compatibility aliases over the registry, the tile-cache stats collector,
-profiler capture via REPRO_PROFILE_DIR — and the load-bearing contract:
+the engine's per-step events, trace counters and profiler spans — and the
+load-bearing contract:
 attaching metrics/tracing changes NO compiled program (byte-identical
 lowering, asserted below)."""
 
@@ -36,7 +37,6 @@ from repro.serve.tracing import (
     JsonlSink,
     ListSink,
     RequestTracer,
-    maybe_profile,
 )
 
 QC = QuantConfig(mode="pquant", r=16, num_experts=1)
@@ -341,20 +341,127 @@ class TestEngineMetrics:
 
 
 # ---------------------------------------------------------------------------
-# profiler capture
+# engine steps: step events, program trace counts, profiler spans
 # ---------------------------------------------------------------------------
 
+STEP_FIELDS = {"step", "prefill_rows", "decode_tokens", "n_decoding",
+               "n_live", "queue_depth", "blocks_used", "preempted",
+               "traces", "wall_s", "fetch_s", "phase_s"}
 
-class TestProfile:
-    def test_env_unset_is_noop(self, monkeypatch):
-        monkeypatch.delenv("REPRO_PROFILE_DIR", raising=False)
-        with maybe_profile("t"):
-            pass  # no trace started, nothing written anywhere
 
-    def test_profile_dir_produces_trace(self, monkeypatch, tmp_path):
-        monkeypatch.setenv("REPRO_PROFILE_DIR", str(tmp_path))
-        with maybe_profile("t"):
-            with maybe_profile("inner"):  # re-entrant bracket no-ops
-                jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))).block_until_ready()
-        files = [p for p in pathlib.Path(tmp_path).rglob("*") if p.is_file()]
-        assert files, "REPRO_PROFILE_DIR set but no trace captured"
+def _steps(sink):
+    return [e for e in sink.records if e["event"] == "step"]
+
+
+def _traces(eng, program):
+    return int(eng.metrics.counter("program_traces_total",
+                                   program=program).value)
+
+
+class TestEngineSteps:
+    def test_one_step_event_per_step(self, params):
+        """Each step() emits one ``step`` event whose pool reading is the
+        allocator's own and whose work adds up to the engine's counters."""
+        sink = ListSink()
+        eng = _engine(params, prefill_chunk=4, tracer=RequestTracer(sink))
+        for uid in range(3):
+            eng.submit(_prompt(uid, n=6 + 3 * uid), max_new_tokens=5,
+                       seed=uid, uid=uid)
+        n = 0
+        while eng._queue or eng._live():
+            eng.step()
+            ev = _steps(sink)[-1]
+            assert ev["step"] == n and set(ev) >= STEP_FIELDS
+            assert ev["blocks_used"] == eng.allocator.used_count
+            assert ev["n_live"] == len(eng._live())
+            assert ev["queue_depth"] == len(eng._queue)
+            assert 0.0 <= ev["fetch_s"] <= ev["wall_s"]
+            n += 1
+        evs = _steps(sink)
+        assert len(evs) == n == eng.snapshot()["counters"][
+            "engine_steps_total"]
+        assert sum(e["prefill_rows"] for e in evs) == eng.prefill_tokens
+        # first tokens come from the prefill slices, the rest from chunks
+        assert sum(e["decode_tokens"] for e in evs) == \
+            eng.tokens_generated - 3
+        chunks = [e for e in sink.records if e["event"] == "decode_chunk"]
+        assert [e["n_decoding"] for e in evs if e["n_decoding"]] == \
+            [e["n_decoding"] for e in chunks]
+        assert all(0 < e["blocks_used"] <= eng.num_blocks for e in chunks)
+
+    def test_step_event_names_its_phases(self, params):
+        sink = ListSink()
+        eng = _engine(params, prefill_chunk=4, tracer=RequestTracer(sink))
+        eng.submit(_prompt(1), max_new_tokens=6, seed=1, uid=1)
+        eng.run()
+        phases = set().union(*(e["phase_s"] for e in _steps(sink)))
+        assert {"serve/admit", "serve/chunked_prefill",
+                "serve/prefill_fetch", "serve/ensure_blocks",
+                "serve/decode_chunk", "serve/decode_dispatch",
+                "serve/decode_fetch", "serve/process_chunk"} <= phases
+        for e in _steps(sink):
+            p = e["phase_s"]
+            if "serve/decode_chunk" in p:
+                assert p["serve/decode_chunk"] >= p["serve/decode_fetch"]
+
+    def test_waiting_for_an_arrival_is_its_own_phase(self, params):
+        clock = ManualClock()
+        sink = ListSink()
+        eng = _engine(params, clock=clock, tracer=RequestTracer(sink))
+        eng.submit(_prompt(1), max_new_tokens=2, seed=1, uid=1,
+                   arrival=0.2)
+        eng.step()
+        (ev,) = _steps(sink)
+        assert "serve/wait_arrival" in ev["phase_s"]
+        assert ev["n_live"] == 0 and ev["queue_depth"] == 1
+
+    def test_a_steady_window_traces_nothing(self, params):
+        """Warm engine, same shapes: no step traces a program; each
+        program was traced once."""
+        sink = ListSink()
+        eng = _engine(params, prefill_chunk=4, tracer=RequestTracer(sink))
+        eng.submit(_prompt(1), max_new_tokens=6, seed=1, uid=1)
+        eng.run()
+        assert _traces(eng, "chunk") == 1
+        assert _traces(eng, "prefill_chunk") == 1
+        sink.records.clear()
+        eng.submit(_prompt(2, n=9), max_new_tokens=6, seed=2, uid=2)
+        eng.run()
+        assert [e["traces"] for e in _steps(sink)] == \
+            [0] * len(_steps(sink))
+        assert _traces(eng, "chunk") == 1
+
+    def test_a_retrace_names_its_program_and_step(self, params):
+        """One-shot admission pads prompts to power-of-two buckets: a
+        prompt in a new bucket retraces the prefill program, and the
+        count lands on the step that admitted it."""
+        sink = ListSink()
+        eng = _engine(params, tracer=RequestTracer(sink))
+        eng.submit(_prompt(1, n=6), max_new_tokens=3, seed=1, uid=1)
+        eng.run()
+        program = ("prefill_bucketed" if eng._prefill_bucketed is not None
+                   else "prefill")
+        before = _traces(eng, program)
+        sink.records.clear()
+        eng.submit(_prompt(2, n=12), max_new_tokens=3, seed=2, uid=2)
+        eng.run()
+        evs = _steps(sink)
+        assert _traces(eng, program) == before + 1
+        assert evs[0]["traces"] >= 1 and "serve/admit" in evs[0]["phase_s"]
+        assert all(e["traces"] == 0 for e in evs[1:])
+
+    def test_profile_holds_the_step_spans(self, params, tmp_path):
+        """``jax.profiler.trace`` around a run captures the engine's span
+        tree on the host timeline."""
+        from jax.profiler import ProfileData
+
+        eng = _engine(params, prefill_chunk=4)
+        eng.submit(_prompt(1), max_new_tokens=6, seed=1, uid=1)
+        with jax.profiler.trace(str(tmp_path)):
+            eng.run()
+        (path,) = pathlib.Path(tmp_path).rglob("*.xplane.pb")
+        names = {e.name for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for e in line.events}
+        assert {"serve/step", "serve/admit", "serve/chunked_prefill",
+                "serve/decode_chunk", "serve/decode_fetch",
+                "serve/process_chunk"} <= names

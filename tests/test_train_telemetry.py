@@ -87,6 +87,32 @@ class TestByteIdenticalLowering:
         assert (step_default.lower(state, batch).as_text()
                 == step_off.lower(state, batch).as_text())
 
+    def test_quant_scopes_change_metadata_only(self, monkeypatch):
+        """The fake-quant named scopes (``quant/weights``, ``quant/acts``)
+        reach the compiled step's metadata and nothing else: without them
+        the compiled program is the same, metadata aside."""
+        import contextlib
+        import re
+
+        state, _ = init_train_state(jax.random.PRNGKey(0), CFG)
+        batch = _batch(CFG)
+
+        def compiled():
+            step = jax.jit(make_train_step(CFG, 10))
+            return step.lower(state, batch).compile().as_text()
+
+        def strip(text):
+            text = text.split("\nFileNames")[0]
+            return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+        scoped = compiled()
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = compiled()
+        assert 'quant/weights' in scoped and 'quant/acts' in scoped
+        assert "quant/" not in bare
+        assert strip(scoped) == strip(bare)
+
 
 # ---------------------------------------------------------------------------
 # probe correctness on hand-built weights
