@@ -69,7 +69,7 @@ NEW_TOKENS = 32
 BLOCK_SIZE = 16
 PREFILL_CHUNK = 256
 DECODE_KERNELS = {"w1a8_gemv", "decoupled_gemv", "int8_matmul",
-                  "paged_attention"}
+                  "paged_attention_decode"}
 PREFILL_KERNELS = {"w1a8_matmul", "decoupled_matmul", "int8_matmul",
                    "paged_attention"}
 LOGIT_PROMPT = 16

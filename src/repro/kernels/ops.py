@@ -28,9 +28,11 @@ winners are also mirrored to a per-backend JSON file
 (``repro.kernels.tile_cache``) loaded on the first lookup, so autotuning
 survives process restarts.
 
-The paged-attention family (``paged_attention`` + ``paged_tiles`` /
-``sweep_paged_tiles`` + the ``paged_attention_enabled`` /
-``paged_attention_supported`` dispatch gates) lives at the bottom of this
+The paged-attention family (``paged_attention``, which picks the T == 1
+decode kernel or the T > 1 grid kernel from the query's shape, +
+``paged_tiles`` / ``sweep_paged_tiles`` for the grid kernel + the
+``paged_attention_enabled`` / ``paged_attention_supported`` dispatch
+gates + the ``paged_path_stats`` path counts) lives at the bottom of this
 module: ``models.attention._paged_scores`` routes the serving stack's
 paged-KV branches here, keeping the ``kv_pool.read`` gather + SDPA path
 as fallback and parity oracle.
@@ -55,6 +57,9 @@ from repro.kernels import ref, tile_cache
 from repro.kernels.decoupled_matmul import decoupled_matmul
 from repro.kernels.int8_matmul import int8_matmul
 from repro.kernels.paged_attention import paged_attention as _paged_attention
+from repro.kernels.paged_attention import (
+    paged_attention_decode as _paged_attention_decode,
+)
 from repro.kernels.rmsnorm_quant import rmsnorm_quant
 from repro.kernels.w1a8_gemv import decoupled_gemv, w1a8_gemv
 from repro.kernels.w1a8_matmul import w1a8_matmul
@@ -560,6 +565,28 @@ def _decoupled_first_gemm_local(
 # columns wide)
 _PAGES_CANDIDATES = (8, 4, 2, 1)
 
+# process-wide count of the paged-attention call sites traced, by the path
+# each took: ``decode`` (T == 1 kernel), ``chunk`` (T > 1 grid kernel) or
+# ``gather`` (the ``kv_pool.read`` + SDPA fallback).  Counted at trace time,
+# so a compiled program counts each of its call sites once; the serving
+# metrics registry pulls them in at snapshot time via a collector
+# (``scheduler._paged_path_stats``), as it does the tile-cache stats.
+_PAGED_PATHS = {"decode": 0, "chunk": 0, "gather": 0}
+
+
+def record_paged_path(path: str) -> None:
+    _PAGED_PATHS[path] += 1
+
+
+def paged_path_stats() -> dict:
+    """Copy of the process-wide paged-attention path counts."""
+    return dict(_PAGED_PATHS)
+
+
+def reset_paged_path_stats() -> None:
+    for k in _PAGED_PATHS:
+        _PAGED_PATHS[k] = 0
+
 
 def paged_attention_enabled() -> bool:
     """Whether the model stack's paged branches dispatch the Pallas kernel.
@@ -695,14 +722,18 @@ def paged_attention(
     """Block-table attention over the paged KV pool (flash-decoding-style
     online softmax, GQA/MQA grouping; T=1 decode, T>1 chunk/prefill).
 
-    The jit'd public wrapper: picks pages-per-step from the autotuned
-    table (``paged_tiles`` / ``sweep_paged_tiles``) and runs interpreted
-    off-TPU.  Callers gate on :func:`paged_attention_enabled` /
+    The jit'd public wrapper: the query's shape alone picks the kernel —
+    T == 1 runs ``paged_attention_decode`` (all-heads pages, live pages
+    only), T > 1 the grid kernel with pages-per-step from the autotuned
+    table (``paged_tiles`` / ``sweep_paged_tiles``) — and counts the path
+    taken (:func:`paged_path_stats`).  Runs interpreted off-TPU.  Callers
+    gate on :func:`paged_attention_enabled` /
     :func:`paged_attention_supported` and keep the ``kv_pool.read``
     gather + SDPA path as fallback and parity oracle
     (``ref.paged_attention_ref``).  Under a multi-device mesh it runs per
     shard of KV heads, the pools' serving placement (``cache_heads``).
     """
+    record_paged_path("decode" if q.shape[1] == 1 else "chunk")
     fn = functools.partial(_paged_attention_local, scale=scale)
     args = (q, kpool, vpool, table, start, kv_lens)
     mesh = _sharding.active_mesh()
@@ -717,6 +748,12 @@ def paged_attention(
 
 def _paged_attention_local(q, kpool, vpool, table, start, kv_lens, scale):
     t, hq, d = q.shape[1:]
+    if t == 1:
+        with _annotate("kernels/paged_attention_decode"):
+            return _paged_attention_decode(
+                q, kpool, vpool, table, start, kv_lens,
+                scale=scale, interpret=not on_tpu(),
+            )
     hkv, bs = kpool.shape[1], kpool.shape[2]
     mb = table.shape[1]
     pages = paged_tiles(t, hq, hkv, d, bs, mb)

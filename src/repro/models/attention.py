@@ -362,6 +362,7 @@ def _paged_scores(
         return ops.paged_attention(
             q, kpool, vpool, table, posv, kv_lens
         ).astype(q.dtype)
+    ops.record_paged_path("gather")
     from repro.serve import kv_pool  # deferred: serve imports models
 
     mb = table.shape[1]

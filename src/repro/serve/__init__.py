@@ -48,13 +48,16 @@ enables it on TPU only — and the static shapes qualify
 head_dim 8-aligned).  The kernel walks each slot's block table in place
 with a flash-decoding online softmax (per-slot work bounded by the
 resident length, never the table capacity) and serves all three tiers
-through the one read path: decode steps (T=1), chunked-prefill slices and
-one-shot prefill (T>1).  The ``kv_pool.read`` gather + SDPA path remains
-the fallback and parity oracle — it is bitwise the dense computation,
-while the kernel is float-rounding-close (online softmax re-associates
-the reduction), which is exactly why the default keeps the fallback on
-CPU where the bit-for-bit cross-layout suites run.  Pages-per-step is
-autotuned per (T, heads, head_dim, block, table-width) signature via
+through the one read path: decode steps (T=1, the all-heads-page decode
+kernel), chunked-prefill slices and one-shot prefill (T>1, the per-head
+grid kernel); the engine's metrics snapshot counts which path each
+traced call site took (``paged_attn_path_*``).  The ``kv_pool.read``
+gather + SDPA path remains the fallback and parity oracle — it is
+bitwise the dense computation, while the kernel is float-rounding-close
+(online softmax re-associates the reduction), which is exactly why the
+default keeps the fallback on CPU where the bit-for-bit cross-layout
+suites run.  The grid kernel's pages-per-step (T > 1) is autotuned per
+(T, heads, head_dim, block, table-width) signature via
 ``ops.sweep_paged_tiles`` and persisted per backend alongside the GEMV
 tile tables (``REPRO_TILE_CACHE`` / ``REPRO_TILE_CACHE_DIR`` env vars).
 

@@ -645,6 +645,17 @@ def _tile_cache_stats() -> dict:
     return {f"tile_cache_{k}": v for k, v in tile_cache.stats().items()}
 
 
+def _paged_path_stats() -> dict:
+    """Snapshot collector: paged-attention call sites traced per path
+    (``decode`` / ``chunk`` / ``gather``; process-wide, counted at trace
+    time by ``kernels.ops``), so an operator sees which kernel decode
+    took."""
+    from repro.kernels import ops
+
+    return {f"paged_attn_path_{k}": v
+            for k, v in ops.paged_path_stats().items()}
+
+
 class ContinuousBatchingEngine:
     """Serving tier 3: request queue + slot admission/eviction over one
     compiled fixed-width decode program (see module docstring).
@@ -839,6 +850,7 @@ class ContinuousBatchingEngine:
         self._phase_s: dict[str, float] = {}
         self._step_decoding = self._step_decode_tokens = 0
         m.register_collector(_tile_cache_stats)
+        m.register_collector(_paged_path_stats)
         self.allocator = (
             kv_pool.BlockAllocator(
                 self.num_blocks,

@@ -323,6 +323,19 @@ class TestEngineMetrics:
         assert col["tile_cache_sweep_ms"] == pytest.approx(4.0)
         tile_cache.reset_stats()
 
+    def test_paged_path_stats_ride_the_snapshot(self, params):
+        from repro.kernels import ops
+
+        ops.reset_paged_path_stats()
+        ops.record_paged_path("decode")
+        ops.record_paged_path("chunk")
+        ops.record_paged_path("chunk")
+        col = _engine(params).snapshot()["collected"]
+        assert col["paged_attn_path_decode"] == 1
+        assert col["paged_attn_path_chunk"] == 2
+        assert col["paged_attn_path_gather"] == 0
+        ops.reset_paged_path_stats()
+
     def test_disabled_observability_lowers_byte_identical(self, params):
         """The hard contract: metrics + tracer attached vs absent must
         produce the SAME compiled decode-chunk program — all

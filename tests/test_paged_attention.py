@@ -9,7 +9,11 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref, tile_cache
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (
+    decode_pages,
+    paged_attention,
+    paged_attention_decode,
+)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -131,6 +135,50 @@ def test_bf16_pools_fp32_accumulation():
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want), atol=0.02, rtol=0.02
     )
+
+
+@pytest.mark.parametrize("pages", [1, 4, None])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (8, 1)])  # G 1, 4, 8
+def test_decode_kernel_matches_reference(hq, hkv, dtype, pages):
+    """The T == 1 kernel against the gather+SDPA reference over a permuted
+    table: slots at length 1, mid-page, exactly on a page boundary and the
+    full table in one batch, one page per step, several (with a dead tail
+    in the last live step) and the derived count.  Pool pages outside the
+    live prefixes hold NaN: a page the walk should skip would show."""
+    b, d, mb = 5, 16, 6
+    bs = 16 if dtype == jnp.bfloat16 else 8  # a whole sublane tile
+    kpool, vpool, table = _setup(b, hkv, d, bs, mb, dtype=dtype)
+    lens = np.asarray([1, bs + 3, 2 * bs, mb * bs, 1], np.int32)
+    live = {int(table[s, pg]) for s in range(b)
+            for pg in range(-(-int(lens[s]) // bs))}
+    dead = np.asarray([i for i in range(kpool.shape[0]) if i not in live])
+    kp = kpool.at[dead].set(jnp.nan)
+    vp = vpool.at[dead].set(jnp.nan)
+    q = _q(b, 1, hq, d, dtype=dtype)
+    start = jnp.asarray(lens - 1)
+    got = paged_attention_decode(
+        q, kp, vp, table, start, jnp.asarray(lens), pages=pages,
+        interpret=True,
+    )
+    assert got.shape == q.shape and got.dtype == dtype
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    want = ref.paged_attention_ref(
+        f32(q), f32(kpool), f32(vpool), table, start, jnp.asarray(lens))
+    # f32 accumulation throughout; a bf16 result is that, rounded once
+    rtol = 2.0**-8 if dtype == jnp.bfloat16 else 0.0
+    np.testing.assert_allclose(
+        np.asarray(f32(got)), np.asarray(want), rtol=rtol, atol=2e-6)
+
+
+def test_decode_pages_fill_the_step_bytes():
+    """Pages per decode step come from the page's bytes and the table
+    width alone: the cell's 64 KB bf16 pages (32 heads of 16 x 64) take
+    four, one page is the floor, the table width the ceiling."""
+    assert decode_pages(32 * 16 * 64 * 2, 64) == 4
+    assert decode_pages(36 * 16 * 80 * 2, 64) == 2
+    assert decode_pages(1 << 20, 64) == 1
+    assert decode_pages(256, 6) == 6
 
 
 # ---------------------------------------------------------------------------
@@ -295,3 +343,54 @@ def test_unsupported_block_size_falls_back(monkeypatch):
         )
         outs[env] = np.asarray(l)
     np.testing.assert_array_equal(outs["0"], outs["1"])
+
+
+def test_query_length_picks_the_kernel(monkeypatch):
+    """``ops.paged_attention`` runs the decode kernel for T == 1 and the
+    grid kernel, with its autotuned pages-per-step, for T > 1."""
+    calls = []
+    for name in ("_paged_attention", "_paged_attention_decode"):
+        real = getattr(ops, name)
+
+        def spy(*a, _real=real, _name=name, **k):
+            calls.append(_name)
+            return _real(*a, **k)
+
+        monkeypatch.setattr(ops, name, spy)
+    b, hq, hkv, d, bs, mb = 2, 4, 2, 8, 8, 2
+    kpool, vpool, table = _setup(b, hkv, d, bs, mb)
+    start = jnp.asarray([0, 5], jnp.int32)
+    for t, kernel in ((1, "_paged_attention_decode"), (3, "_paged_attention")):
+        calls.clear()
+        q = _q(b, t, hq, d)
+        got = ops.paged_attention(q, kpool, vpool, table, start, start + t)
+        want = ref.paged_attention_ref(q, kpool, vpool, table, start,
+                                       start + t)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6)
+        assert calls == [kernel]
+
+
+def test_paged_path_counter(monkeypatch):
+    """Each traced paged-attention call site counts its path: a T == 1
+    trace ``decode``, a T > 1 trace ``chunk``, and a shape the kernel
+    refuses (block 4) the ``gather`` fallback."""
+    from repro.models import attention
+
+    monkeypatch.setenv("REPRO_PAGED_ATTN", "1")
+    b, hq, hkv, d = 2, 4, 2, 8
+    pos = jnp.zeros((b,), jnp.int32)
+    ops.reset_paged_path_stats()
+    try:
+        for t, bs in ((1, 8), (3, 8), (1, 4)):
+            kpool, vpool, table = _setup(b, hkv, d, bs, 2)
+            q = _q(b, t, hq, d)
+            posmat = pos[:, None] + jnp.arange(t)[None]
+            jax.eval_shape(
+                lambda q, kp, vp, tb: attention._paged_scores(
+                    q, kp, vp, tb, pos, posmat, t, None),
+                q, kpool, vpool, table)
+        assert ops.paged_path_stats() == {"decode": 1, "chunk": 1,
+                                          "gather": 1}
+    finally:
+        ops.reset_paged_path_stats()

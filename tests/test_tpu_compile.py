@@ -18,7 +18,10 @@ from repro.core.packing import padded_k
 from repro.kernels import ops
 from repro.kernels.decoupled_matmul import decoupled_matmul
 from repro.kernels.int8_matmul import int8_matmul
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (
+    paged_attention,
+    paged_attention_decode,
+)
 from repro.kernels.rmsnorm_quant import rmsnorm_quant
 from repro.kernels.w1a8_gemv import decoupled_gemv, w1a8_gemv
 from repro.kernels.w1a8_matmul import w1a8_matmul
@@ -137,3 +140,14 @@ def test_gemv_compiles_at_2_6b_width(one_chip):
 @pytest.mark.parametrize("t", [1, 16])
 def test_paged_attention_compiles_at_head_dim_80(one_chip, t):
     assert _paged(one_chip, t, H26, H26, HD26) == {"paged_attention"}
+
+
+@pytest.mark.parametrize("hq,d", [(32, 64), (H26, HD26)])
+def test_paged_attention_decode_compiles(one_chip, hq, d):
+    """The T == 1 kernel at the pquant-1.3b serving cell's pool (block 16,
+    64 table entries, 512 blocks, bf16) and at the 2.6b head width."""
+    pool = ((512, hq, 16, d), BF16)
+    assert _compile(
+        one_chip, paged_attention_decode, ((8, 1, hq, d), BF16), pool, pool,
+        ((8, 64), I32), ((8,), I32), ((8,), I32),
+    ) == {"paged_attention_decode"}
